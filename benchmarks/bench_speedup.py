@@ -2,12 +2,14 @@
 executor (sleep-modelled party compute so wall-clock parallelism is real;
 one party is a 40% straggler, as in the paper's setup) — plus the
 devices x parties sweep of the SHARDED device trainer: step throughput of
-core/asyrevel.train_sharded at 1/2/4 CPU host devices, measured on real
-parallel hardware rather than a sleep model.
+core/asyrevel.train_sharded at 1/2/4 XLA host (CPU) devices.
 
 Each device count runs in its own subprocess because
 --xla_force_host_platform_device_count must be set before jax initializes
-(``python -m benchmarks.bench_speedup --worker`` is that subprocess)."""
+(``python -m benchmarks.bench_speedup --worker`` is that subprocess). The
+sweep measures CPU host devices by design, so every worker is pinned to
+the CPU (``JAX_PLATFORMS=cpu``): on a chip host it then never races the
+parent for the TPU, and its rows say ``cpu`` in their names."""
 from __future__ import annotations
 
 import json
@@ -94,7 +96,7 @@ def _sweep_worker(batch: int, steps: int, d: int, q: int) -> dict:
 
 
 def _spawn_sweep(devices: int, q: int):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     kept = [f for f in env.get("XLA_FLAGS", "").split()
             if "xla_force_host_platform_device_count" not in f]
     env["XLA_FLAGS"] = " ".join(
@@ -112,24 +114,20 @@ def _spawn_sweep(devices: int, q: int):
 
 
 def device_sweep():
-    """Devices x parties throughput of the sharded device trainer."""
+    """Devices x parties throughput of the sharded device trainer on CPU
+    host devices. A failed worker raises, failing the suite."""
     rows = []
     for q in SWEEP_PARTIES:
         base = None
         for dev in SWEEP_DEVICES:
-            try:
-                r = _spawn_sweep(dev, q)
-            except Exception as e:  # noqa: BLE001 — report, keep sweeping
-                rows.append((f"fig4_device_throughput_q{q}_dev{dev}", 0.0,
-                             f"error={type(e).__name__}"))
-                continue
+            r = _spawn_sweep(dev, q)
             sps = r["steps_per_s"]
             base = sps if dev == 1 else base
             # no float-parseable NaN: it would survive into the JSON
             # artifact and break strict parsers
             speedup = f"{sps / base:.2f}" if base else "na"
             rows.append((
-                f"fig4_device_throughput_q{q}_dev{dev}", 1e6 / sps,
+                f"fig4_cpu_device_throughput_q{q}_dev{dev}", 1e6 / sps,
                 f"devices={dev};parties={q};batch={r['batch']};"
                 f"steps_per_s={sps:.2f};speedup_vs_1dev={speedup};"
                 f"ideal={dev};finite={r['loss_finite']}"))

@@ -71,19 +71,19 @@ def _parse_derived(derived: str) -> dict:
 
 def write_artifact(suite_key: str, rows, ok: bool, elapsed_s: float):
     """Persist one suite's rows as BENCH_<artifact>.json in the repo root."""
+    import jax
+
     _, title, artifact = SUITES[suite_key]
-    try:
-        import jax
-        devices = len(jax.devices())
-    except Exception:  # noqa: BLE001
-        devices = None
+    devices = jax.devices()
     payload = {
         "suite": suite_key,
         "title": title,
         "ok": ok,
         "elapsed_s": round(elapsed_s, 2),
         "generated_unix": time.time(),
-        "device_count": devices,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "rows": [{"name": name, "us_per_call": us, "derived": derived,
                   "metrics": _parse_derived(derived)}
                  for name, us, derived in rows],

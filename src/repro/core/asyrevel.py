@@ -305,7 +305,6 @@ def make_sharded_train_fn(model: VFLModel, vfl: VFLConfig, n: int,
     ``data`` axis. Returned separately from ``train_sharded`` so repeat
     callers (throughput benches) reuse one compiled executable. ``n`` is
     the dataset's sample count (index-draw range)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.ctx import suspend_constraints
@@ -339,11 +338,11 @@ def make_sharded_train_fn(model: VFLModel, vfl: VFLConfig, n: int,
     rep = replicated_pspecs
 
     def sharded(state, keys, data):
-        return shard_map(
+        return jax.shard_map(
             scan_fn, mesh=mesh,
             in_specs=(rep(state), P(), rep(data)),
             out_specs=(rep(state), P()),
-            check_rep=False)(state, keys, data)
+            check_vma=False)(state, keys, data)
 
     return jax.jit(sharded)
 

@@ -395,13 +395,13 @@ class ZOExchange:
         return zoo.apply_zo_update(w, key, self.direction, coeff, lr)
 
     def apply_fused(self, w, key, coeff, lr: float, *,
-                    impl: str = "pallas", interpret: bool = True):
+                    impl: str = "pallas", interpret: bool | None = None):
         """Fused kernels path (Rademacher directions only): the per-leaf
         sign bits regenerate from the same per-leaf keys
         ``direction_tree`` uses, so this is bit-compatible with
         apply_from_seed(direction='rademacher'). ``impl='pallas'`` is the
-        TPU kernel (interpret-mode here); ``impl='xla'`` the one-dispatch
-        host chain."""
+        TPU kernel (compiled on a TPU, interpreted elsewhere);
+        ``impl='xla'`` the one-dispatch host chain."""
         assert self.direction == "rademacher", \
             "the fused kernel derives u from sign bits (Rademacher law)"
         scale = jnp.asarray(lr * coeff, jnp.float32)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 
 def _kernel(x_ref, w_ref, u_ref, y0_ref, y1_ref, acc0_ref, acc1_ref, *,
             mu: float, n_k: int):
@@ -30,12 +32,15 @@ def _kernel(x_ref, w_ref, u_ref, y0_ref, y1_ref, acc0_ref, acc1_ref, *,
 
     # f32 operands + f32 accumulators: bf16 inputs would otherwise lose
     # the mu*u perturbation (|mu*u| << |w| vs bf16's ~8-bit mantissa) and
-    # round per-tile partial products
+    # round per-tile partial products. HIGHEST keeps the MXU from
+    # truncating the f32 operands to one bf16 pass on the chip.
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
-    acc0_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
-    acc1_ref[...] += jnp.dot(x, w + mu * u,
+    hi = jax.lax.Precision.HIGHEST
+    acc0_ref[...] += jnp.dot(x, w, precision=hi,
+                             preferred_element_type=jnp.float32)
+    acc1_ref[...] += jnp.dot(x, w + mu * u, precision=hi,
                              preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -47,7 +52,7 @@ def _kernel(x_ref, w_ref, u_ref, y0_ref, y1_ref, acc0_ref, acc1_ref, *,
 @functools.partial(jax.jit, static_argnames=("mu", "bm", "bn", "bk",
                                              "interpret"))
 def dual_matmul_pallas(x, w, u, *, mu: float, bm: int = 128, bn: int = 128,
-                       bk: int = 512, interpret: bool = True):
+                       bk: int = 512, interpret: bool | None = None):
     """x: (M,K); w,u: (K,N). Returns (x@w, x@(w+mu*u)), fp32-accumulated."""
     M, K = x.shape
     _, N = w.shape
@@ -74,5 +79,5 @@ def dual_matmul_pallas(x, w, u, *, mu: float, bm: int = 128, bn: int = 128,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w, u)

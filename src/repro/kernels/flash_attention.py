@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -46,7 +48,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         q = q_ref[0].astype(jnp.float32)            # (bq, hd)
         k = k_ref[0].astype(jnp.float32)            # (bkv, hd)
         v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        # fp32 softmax needs f32 scores: HIGHEST keeps the MXU from
+        # rounding the operands to one bf16 pass on the chip
+        hi = jax.lax.Precision.HIGHEST
+        s = jnp.dot(q, k.T, precision=hi,
+                    preferred_element_type=jnp.float32) * scale
         if causal:
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32,
                                                        (bq, bkv), 0)
@@ -59,7 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p, v, precision=hi, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
@@ -71,7 +77,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv",
                                              "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
-                           bkv: int = 128, interpret: bool = True):
+                           bkv: int = 128, interpret: bool | None = None):
     """q,k,v: (BH, S, hd) same-head layout. Returns (BH, S, hd)."""
     BH, S, hd = q.shape
     bq, bkv = min(bq, S), min(bkv, S)
@@ -94,5 +100,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

@@ -10,8 +10,9 @@ the whole chain. Every op ships two interchangeable implementations:
   impl='xla'     ONE jitted elementwise chain — the production fast path
                  on CPU executors (a single dispatch replaces the
                  unfused seam's ~8 per-op eager dispatches);
-  impl='pallas'  the TPU kernel (interpret mode on this CPU container),
-                 reading parameters/payload blocks once and writing the
+  impl='pallas'  the TPU kernel (compiled on a TPU, interpreted on other
+                 platforms — kernels/platform.py), reading
+                 parameters/payload blocks once and writing the
                  encoded result once — no intermediate u, clipped-c, or
                  noised-c array ever lands in HBM. int8 is two passes
                  (masked block absmax, then quantize), both recomputing
@@ -27,11 +28,9 @@ a short, fixed float chain (mantissa-fill to [0,1), affine to the open
 interval, then erf_inv / log1p). The helpers below replicate those
 chains bit-for-bit from the bits (pinned in tests/test_kernels.py), so
 both implementations take the SAME uint32 operands the MeZO-style
-``zo_update`` kernel already uses — on real TPU the bits come from the
-on-chip PRNG (``pltpu.prng_random_bits``); here they are operands so
-the CPU-interpret oracle is bit-exact. Under the existing per-round key
-derivation (``_dp_key`` / ``_codec_key``) a fused exchange is therefore
-bitwise identical to the unfused one, and the PR-4/PR-5 TCP-vs-memory
+``zo_update`` kernel already uses, on every platform. Under the existing
+per-round key derivation (``_dp_key`` / ``_codec_key``) a fused exchange
+is therefore bitwise identical to the unfused one, and the TCP-vs-memory
 parity pins survive with ``fused=True`` unchanged.
 
 The perturb/apply side reuses kernels/zo_update.py: ``w + mu*u`` is the
@@ -50,9 +49,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.zo_update import (rounded_product, rounded_quotient,
                                      runtime_zero, zo_update_pallas)
 
+# every payload pads to whole 1024-lane blocks: Mosaic lays a rank-1 int8
+# or bf16 block out in packed sublanes and refuses a block that does not
+# fill them, so small payloads may not take a block of their own size
 _BLOCK = 1024
 _F32_ONE = np.uint32(0x3F800000)
 # the open-interval lower bounds jax.random uses before erf_inv / log1p
@@ -166,7 +169,9 @@ def _encode_math(d, rnd_bits, codec: str, z=None):   # zvlint: bit-exact
 # SMEM scalar layout (1, 3): [clip, noise_scale, quant_scale]. Static
 # flags select the stages the kernel body actually emits; unused operands
 # are traced away. Block absmax masks the pad lanes with a global-index
-# iota (|defended| >= 0, so masked-to-0 lanes never win the max).
+# iota (|defended| >= 0, so masked-to-0 lanes never win the max) and
+# writes the block's maximum across one lane-aligned (1, 128) row of a
+# (grid, 1, 128) output: Mosaic accepts no rank-1 output block of size 1.
 
 def _make_defend_kernel(*, mechanism, has_dp, has_noise, stage, codec,
                         has_rnd, block, n):
@@ -183,8 +188,8 @@ def _make_defend_kernel(*, mechanism, has_dp, has_noise, stage, codec,
             # integer FMA contraction is exact, no rounding to drift
             lane = (jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
                     + pl.program_id(0) * block)
-            o_ref[...] = jnp.max(
-                jnp.where(lane < n, jnp.abs(c), 0.0), keepdims=True)
+            o_ref[...] = jnp.broadcast_to(
+                jnp.max(jnp.where(lane < n, jnp.abs(c), 0.0)), o_ref.shape)
         elif stage == "quant":
             x = c / sm_ref[0, 2]
             if has_rnd:
@@ -198,7 +203,7 @@ def _make_defend_kernel(*, mechanism, has_dp, has_noise, stage, codec,
 
 
 def _defend_call(kernel, sm, z, flat, dpb, rnb, out_shape, out_dtype, block,
-                 grid, interpret, out_block=None):
+                 grid, interpret, out_spec=None):
     return pl.pallas_call(
         kernel,
         grid=(grid,),
@@ -209,9 +214,9 @@ def _defend_call(kernel, sm, z, flat, dpb, rnb, out_shape, out_dtype, block,
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
         ],
-        out_specs=pl.BlockSpec((out_block or block,), lambda i: (i,)),
+        out_specs=out_spec or pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(sm, z.reshape(1), flat, dpb, rnb)
 
 
@@ -223,7 +228,7 @@ def _defended_encode_pallas(c, dp_bits, rnd_bits, dp, codec, z, interpret):
     shape = jnp.shape(c)
     flat = jnp.ravel(jnp.asarray(c, jnp.float32))
     n = flat.shape[0]
-    block = min(_BLOCK, max(n, 1))
+    block = _BLOCK
     pad = (-n) % block
     grid = (n + pad) // block
     flat = _pad1d(flat, pad)
@@ -248,8 +253,9 @@ def _defended_encode_pallas(c, dp_bits, rnd_bits, dp, codec, z, interpret):
         raise ValueError(f"no fused encode for codec {codec!r}")
     # pass 1: masked per-block absmax of the defended values (never stored)
     part = _defend_call(mk(stage="absmax"), sm, z, flat, dpb, rnb,
-                        (grid,), jnp.float32, block, grid, interpret,
-                        out_block=1)
+                        (grid, 1, 128), jnp.float32, block, grid, interpret,
+                        out_spec=pl.BlockSpec((1, 1, 128),
+                                              lambda i: (i, 0, 0)))
     qscale = rounded_quotient(jnp.maximum(jnp.max(part), 1e-12), 127.0, z)
     # pass 2: recompute defended in-register, quantize against qscale
     sm2 = sm.at[0, 2].set(qscale)
@@ -259,7 +265,8 @@ def _defended_encode_pallas(c, dp_bits, rnd_bits, dp, codec, z, interpret):
 
 
 def defended_encode(c, dp_bits, rnd_bits, dp, codec: str, *,
-                    impl: str = "xla", interpret: bool = True, z=None):
+                    impl: str = "xla", interpret: bool | None = None,
+                    z=None):
     """clip -> noise -> codec-encode one payload from raw PRNG bits.
 
     ``dp_bits``/``rnd_bits`` are uint32 arrays shaped like ``c`` (or
@@ -310,7 +317,8 @@ def _encode_up_jit(ex, c, key, z, impl, interpret):
                            impl=impl, interpret=interpret, z=z)
 
 
-def encode_up_fused(ex, c, key, impl: str = "xla", interpret: bool = True):
+def encode_up_fused(ex, c, key, impl: str = "xla",
+                    interpret: bool | None = None):
     return _encode_up_jit(ex, c, key, runtime_zero(), impl, interpret)
 
 
@@ -321,7 +329,7 @@ def _roundtrip_up_jit(ex, c, key, z, impl, interpret):
 
 
 def roundtrip_up_fused(ex, c, key, impl: str = "xla",
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     return _roundtrip_up_jit(ex, c, key, runtime_zero(), impl, interpret)
 
 
@@ -348,7 +356,7 @@ def _leaf_bits(tree, key):
 
 
 def zo_apply(w_tree, key, scale, *, impl: str = "xla",   # zvlint: bit-exact
-             interpret: bool = True):
+             interpret: bool | None = None):
     """w - scale * u(key) with Rademacher u regenerated from the seed,
     never stored. ``scale`` is lr*coeff (or -mu for a perturbation).
     Bitwise equal to zoo.apply_zo_update(dist='rademacher') — impl='xla'
@@ -371,7 +379,7 @@ def zo_apply(w_tree, key, scale, *, impl: str = "xla",   # zvlint: bit-exact
 
 
 def perturb(w_tree, key, mu: float, *, impl: str = "xla",   # zvlint: bit-exact
-            interpret: bool = True):
+            interpret: bool | None = None):
     """(w + mu*u, u) with Rademacher u — the fused twin of zoo.perturb.
     The xla impl mirrors the oracle's formula exactly (bitwise for every
     dtype); pallas routes through the zo_update kernel at scale=-mu

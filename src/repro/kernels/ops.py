@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; on TPU pass
-interpret=False). The wrappers handle layout plumbing — GQA group
+``interpret=None`` lets the platform decide (kernels/platform.py): the
+kernels compile on a TPU and interpret elsewhere. The wrappers handle
+layout plumbing — GQA group
 expansion for attention, pytree flattening for the ZO update — so callers
 stay shape-simple.
 """
@@ -15,13 +16,14 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.zo_update import zo_update_pallas
 
 
-def dual_matmul(x, w, u, mu: float, *, interpret: bool = True, **tiles):
+def dual_matmul(x, w, u, mu: float, *, interpret: bool | None = None,
+                **tiles):
     """(x@w, x@(w+mu*u)) with one pass over x/w. x: (M,K), w/u: (K,N)."""
     return dual_matmul_pallas(x, w, u, mu=mu, interpret=interpret, **tiles)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = True,
-                    **tiles):
+def flash_attention(q, k, v, *, causal: bool = True,
+                    interpret: bool | None = None, **tiles):
     """q: (B,S,H,hd); k,v: (B,S,KV,hd) GQA. Returns (B,S,H,hd)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
@@ -36,7 +38,7 @@ def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = True,
     return o.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
 
-def zo_update(params, bits_tree, scale, *, interpret: bool = True):
+def zo_update(params, bits_tree, scale, *, interpret: bool | None = None):
     """Apply the fused seed-replay update leaf-wise over a pytree.
     Ragged leaf sizes are handled inside ``zo_update_pallas`` (pad to a
     block multiple, slice the tail off)."""

@@ -5,9 +5,9 @@ step and u is the random direction. Materializing u doubles parameter
 traffic. With seed-replay + Rademacher directions (u_i = +-1, E[uu^T] = I —
 a valid two-point-estimator law), u derives from one random BIT per
 element: the kernel reads w and the packed bits, forms u in-register, and
-writes the update — no f32 u ever exists in HBM. (On real TPU the bits
-themselves come from the on-chip PRNG via pltpu.prng_random_bits; here they
-are a uint32 operand so the CPU-interpret oracle is bit-exact.)
+writes the update — no f32 u ever exists in HBM. The bits are a uint32
+operand on every platform, so the kernel (compiled or interpreted) replays
+exactly the stream the unfused oracle draws.
 
 coeff arrives in SMEM as a (1,1) scalar so the same compiled kernel serves
 every step.
@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import interpret_mode
 
 
 @functools.lru_cache(maxsize=None)
@@ -94,13 +96,13 @@ def _zo_update_jit(w, bits, scale, z, *, block, interpret):
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N + pad,), w.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(scale2d, z.reshape(1), w, bits)
     return out[:N] if pad else out
 
 
 def zo_update_pallas(w, bits, scale, *, block: int = 1024,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """w: (N,) params; bits: (N,) uint32; scale: () f32 = lr*coeff.
 
     Returns w - scale * rademacher(bits), bit-identical to the eager

@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.launch import steps as step_lib
 from repro.models import build_model
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import MetricLogger
 
 
@@ -32,6 +33,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)
     log = MetricLogger(f"serve:{args.arch}")

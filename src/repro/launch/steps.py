@@ -121,8 +121,6 @@ def make_vfl_zoo_step(model: Model, vfl: VFLConfig, codec: str | None = None,
             return asyrevel.asyrevel_step(vm, vfl, state, batch, ex)
         return vm, init, step
 
-    from jax.experimental.shard_map import shard_map
-
     from repro.sharding.ctx import suspend_constraints
     from repro.sharding.rules import batch_pspecs, replicated_pspecs
 
@@ -133,11 +131,11 @@ def make_vfl_zoo_step(model: Model, vfl: VFLConfig, codec: str | None = None,
             return asyrevel.asyrevel_step(pm, vfl, state, batch, ex_sharded)
 
     def step(state, batch):
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(replicated_pspecs(state),
                       batch_pspecs(batch, mesh, batch_axes=(data_axis,))),
             out_specs=(replicated_pspecs(state), jax.sharding.PartitionSpec()),
-            check_rep=False)(state, batch)
+            check_vma=False)(state, batch)
 
     return vm, init, step

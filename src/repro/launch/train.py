@@ -21,6 +21,7 @@ import argparse
 import os
 import sys
 import time
+from typing import Callable, NamedTuple
 
 # --data-parallel N on CPU needs N XLA host devices, and that must be
 # configured BEFORE jax initializes — so peek at argv before the jax
@@ -55,11 +56,13 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import INPUT_SHAPES, VFLConfig, get_config
+from repro.core.asyrevel import AsyState
 from repro.data.synthetic import make_lm_dataset
 from repro.launch import steps as step_lib
 from repro.models import build_model
 from repro.obs.metrics import ObsMetricLogger
 from repro.optim.schedules import make_schedule
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def parse_args(argv=None):
@@ -289,6 +292,13 @@ def make_batch_arrays(cfg, n, seq_len, seed):
     return data
 
 
+def role_platforms(srv: dict, parties: dict) -> str:
+    """'server:cpu,party0:cpu,...' — the JAX platform each role ran on."""
+    roles = [f"server:{srv['platform']}"] + [
+        f"party{m}:{parties[m]['platform']}" for m in sorted(parties)]
+    return ",".join(roles)
+
+
 def run_tcp(args, cfg, log):
     """--transport tcp: the multi-process federation runtime. The server
     and each party are separate OS processes over real sockets running
@@ -331,6 +341,8 @@ def run_tcp(args, cfg, log):
         trace_dir=args.trace, monitor=args.monitor)
     res = run_federation(spec, rounds=args.steps, plan=plan, cfg=cfg_rt,
                          ckpt_root=args.ckpt_dir, resume=args.resume)
+    log.log(0, platforms=role_platforms(srv=res["server"],
+                                         parties=res["parties"]))
     h = history_losses(res)
     srv = res["server"]
     # a --resume of an already-complete federation has no new rounds
@@ -384,6 +396,7 @@ def run_serve(args, cfg, log):
                               cache_entries=sc.cache_entries,
                               ckpt_root=args.ckpt_dir)
         met = res["metrics"]
+        log.log(0, platforms=role_platforms(srv=res, parties=res["parties"]))
         extra = ({"alerts": len(res["monitor"]["alerts"])}
                  if "monitor" in res else {})
         log.log(sc.requests, transport="tcp", served=met["served"],
@@ -417,7 +430,11 @@ def run_serve(args, cfg, log):
 
 
 def main(argv=None):
+    """Run the launcher. Returns the final loss (lm), the ``ZooRun`` of an
+    in-process vfl-zoo run, the last server loss of a tcp federation, or
+    the number of predictions served (--serve)."""
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     monitor = None
     if args.trace:
@@ -452,61 +469,83 @@ def _dispatch(args, cfg):
     if args.transport == "tcp":
         return run_tcp(args, cfg,
                        ObsMetricLogger(f"train:{args.arch}:vfl-zoo-tcp"))
+    if args.mode == "vfl-zoo":
+        return run_vfl_zoo(args, cfg)
+    model, key, n, data = _model_and_data(args, cfg)
+    log = ObsMetricLogger(f"train:{args.arch}:lm")
+    sched_name = args.schedule or (
+        "wsd" if args.arch.startswith("minicpm") else "cosine")
+    sched = make_schedule(sched_name, args.lr, args.steps,
+                          warmup=max(1, args.steps // 20))
+    state = step_lib.make_train_state(
+        model, key,
+        state_dtype=(jnp.bfloat16 if args.opt_state_dtype == "bf16"
+                     else jnp.float32))
+    start_step = 0
+    rng = np.random.default_rng(args.seed)
+    if args.resume:
+        from repro.checkpoint import latest_step, restore_checkpoint
+        step0 = latest_step(args.ckpt_dir)
+        if step0 is not None:
+            restored, _ = restore_checkpoint(
+                args.ckpt_dir,
+                {"params": state.params, "opt": state.opt}, step0)
+            # a CONTINUATION, not a warm-started replay: optimizer
+            # moments and the schedule step resume where they were,
+            # and the data stream fast-forwards past consumed batches
+            state = step_lib.TrainState(
+                restored["params"], restored["opt"],
+                jnp.asarray(step0, jnp.int32))
+            start_step = step0
+            for _ in range(step0):
+                rng.integers(0, n, args.batch_size)
+            log.log(0, resumed_from=step0)
+    train_step = jax.jit(step_lib.make_train_step(model, sched))
+    t0 = time.perf_counter()
+    for s in range(args.steps):
+        idx = rng.integers(0, n, args.batch_size)
+        batch = jax.tree.map(lambda a: a[idx], data)
+        state, (loss, metrics) = train_step(state, batch)
+        if s % args.log_every == 0 or s == args.steps - 1:
+            log.log(start_step + s, loss=loss, ce=metrics["ce"],
+                    aux=metrics["aux"], lr=sched(start_step + s))
+    dt = time.perf_counter() - t0
+    log.log(args.steps, done=1, steps_per_s=args.steps / dt)
+    if args.ckpt_dir:
+        # a resumed run commits PAST the restored step, or the next
+        # resume would restore the pre-continuation checkpoint and
+        # silently discard this run's work
+        save_checkpoint(args.ckpt_dir, start_step + args.steps,
+                        {"params": state.params, "opt": state.opt},
+                        {"arch": args.arch, "mode": "lm"})
+    return float(loss)
+
+
+class ZooRun(NamedTuple):
+    """What an in-process vfl-zoo run leaves: the final AsyREVEL state,
+    the per-step server losses h, per-step wall seconds, each taken
+    around ``block_until_ready`` (step 0 includes the compile), and the
+    jitted step it called."""
+    state: AsyState
+    losses: list
+    step_s: list
+    step: Callable
+
+
+def _model_and_data(args, cfg):
     model = build_model(cfg)
-    log = ObsMetricLogger(f"train:{args.arch}:{args.mode}")
     key = jax.random.key(args.seed)
     n = max(64, args.batch_size * 8)
-    data = make_batch_arrays(cfg, n, args.seq_len, args.seed)
+    return model, key, n, make_batch_arrays(cfg, n, args.seq_len, args.seed)
 
-    if args.mode == "lm":
-        sched_name = args.schedule or (
-            "wsd" if args.arch.startswith("minicpm") else "cosine")
-        sched = make_schedule(sched_name, args.lr, args.steps,
-                              warmup=max(1, args.steps // 20))
-        state = step_lib.make_train_state(
-            model, key,
-            state_dtype=(jnp.bfloat16 if args.opt_state_dtype == "bf16"
-                         else jnp.float32))
-        start_step = 0
-        rng = np.random.default_rng(args.seed)
-        if args.resume:
-            from repro.checkpoint import latest_step, restore_checkpoint
-            step0 = latest_step(args.ckpt_dir)
-            if step0 is not None:
-                restored, _ = restore_checkpoint(
-                    args.ckpt_dir,
-                    {"params": state.params, "opt": state.opt}, step0)
-                # a CONTINUATION, not a warm-started replay: optimizer
-                # moments and the schedule step resume where they were,
-                # and the data stream fast-forwards past consumed batches
-                state = step_lib.TrainState(
-                    restored["params"], restored["opt"],
-                    jnp.asarray(step0, jnp.int32))
-                start_step = step0
-                for _ in range(step0):
-                    rng.integers(0, n, args.batch_size)
-                log.log(0, resumed_from=step0)
-        train_step = jax.jit(step_lib.make_train_step(model, sched))
-        t0 = time.perf_counter()
-        for s in range(args.steps):
-            idx = rng.integers(0, n, args.batch_size)
-            batch = jax.tree.map(lambda a: a[idx], data)
-            state, (loss, metrics) = train_step(state, batch)
-            if s % args.log_every == 0 or s == args.steps - 1:
-                log.log(start_step + s, loss=loss, ce=metrics["ce"],
-                        aux=metrics["aux"], lr=sched(start_step + s))
-        dt = time.perf_counter() - t0
-        log.log(args.steps, done=1, steps_per_s=args.steps / dt)
-        if args.ckpt_dir:
-            # a resumed run commits PAST the restored step, or the next
-            # resume would restore the pre-continuation checkpoint and
-            # silently discard this run's work
-            save_checkpoint(args.ckpt_dir, start_step + args.steps,
-                            {"params": state.params, "opt": state.opt},
-                            {"arch": args.arch, "mode": "lm"})
-        return float(loss)
 
-    # --- vfl-zoo: the paper's technique wrapping this architecture -------
+def run_vfl_zoo(args, cfg, mesh=None) -> ZooRun:
+    """--mode vfl-zoo in process: the paper's technique wrapping this
+    architecture (launch/steps.make_vfl_zoo_step), one jitted step per
+    minibatch. ``mesh`` runs the sharded path on that mesh; by default
+    ``--data-parallel N > 1`` builds one over the first N devices."""
+    model, key, n, data = _model_and_data(args, cfg)
+    log = ObsMetricLogger(f"train:{args.arch}:vfl-zoo")
     assert cfg.d_model % args.parties == 0, \
         f"--parties must divide d_model={cfg.d_model}"
     dp = make_dp(args)
@@ -517,14 +556,13 @@ def _dispatch(args, cfg):
         log.log(0, dp_epsilon=args.dp_epsilon,
                 dp_sigma=(dp.noise_multiplier
                           if dp.noise_multiplier is not None else 0.0))
-    mesh = None
-    if args.data_parallel > 1:
+    if mesh is None and args.data_parallel > 1:
         from repro.launch.mesh import make_data_mesh
         mesh = make_data_mesh(args.data_parallel)
-        assert args.batch_size % args.data_parallel == 0, \
+    if mesh is not None:
+        assert args.batch_size % mesh.size == 0, \
             "--batch-size must divide by --data-parallel"
-        log.log(0, data_parallel=args.data_parallel,
-                devices=len(jax.devices()))
+        log.log(0, data_parallel=mesh.size, devices=len(jax.devices()))
     vm, init, step = step_lib.make_vfl_zoo_step(model, vfl, mesh=mesh)
     state = init(key)
     start_step = 0
@@ -553,15 +591,33 @@ def _dispatch(args, cfg):
             for _ in range(step0):
                 rng.integers(0, n, args.batch_size)
             log.log(0, resumed_from=step0)
+    place = None
+    if mesh is not None:
+        # state replicated and every batch split over 'data', where the
+        # sharded step keeps them: the first call then compiles the
+        # steady-state program, and no later call recompiles for a
+        # change of placement
+        from repro.sharding.rules import (batch_pspecs, replicated_pspecs,
+                                          shard_tree)
+        state = shard_tree(state, mesh, replicated_pspecs(state))
+        place = lambda b: shard_tree(  # noqa: E731
+            b, mesh, batch_pspecs(b, mesh, batch_axes=("data",)))
     zoo_step = jax.jit(step)
-    losses = []
+    losses, step_s = [], []
     for s in range(args.steps):
         idx = rng.integers(0, n, args.batch_size)
         batch = jax.tree.map(lambda a: a[idx], data)
-        state, h = zoo_step(state, batch)
+        if place is not None:
+            batch = place(batch)
+        t0 = time.perf_counter()
+        state, h = jax.block_until_ready(zoo_step(state, batch))
+        step_s.append(time.perf_counter() - t0)
         losses.append(float(h))
         if s % args.log_every == 0 or s == args.steps - 1:
             log.log(start_step + s, h=h)
+    log.log(args.steps, done=1, first_step_s=step_s[0],
+            warm_step_s=float(np.median(step_s[1:])) if len(step_s) > 1
+            else float("nan"))
     if args.network:
         # the scan trainer exchanges the same per-round payloads as the
         # host executor; price them on the chosen channel profile so the
@@ -593,7 +649,7 @@ def _dispatch(args, cfg):
                         {"w0": state.w0, "parties": state.parties,
                          "hist": state.hist},
                         {"arch": args.arch, "mode": "vfl-zoo"})
-    return losses[-1]
+    return ZooRun(state, losses, step_s, zoo_step)
 
 
 if __name__ == "__main__":

@@ -355,22 +355,30 @@ class RttDetector:
 
 class ChainDecayDetector:
     """Online chain completeness: every ``server_handle`` for round r
-    checks the chain of round ``r - settle`` (party_round span + c_up
-    wire + server_handle) — the settle window absorbs cross-socket
-    arrival skew. Fires when the running completeness over at least
-    ``min_checked`` chains decays below ``threshold`` (95% is the
-    acceptance gate); re-arms on recovery."""
+    makes the chain of round ``r - settle`` (party_round span + c_up
+    wire + server_handle) due, and a due chain is checked once the
+    party's own stream has also reached round r. Each process streams
+    over its own socket and the collector drains them one at a time, so
+    one side may run many rounds ahead of the other; waiting for both
+    keeps the verdict independent of that order. A party whose stream
+    went silent (its mirror link dropped) has its chains checked once the
+    server is ``lag`` rounds past them. Fires when the running
+    completeness over at least ``min_checked`` chains decays below
+    ``threshold`` (95% is the acceptance gate); re-arms on recovery."""
 
     name = "chain_decay"
 
     def __init__(self, threshold: float = 0.95, settle: int = 2,
-                 min_checked: int = 5):
+                 min_checked: int = 5, lag: int = 32):
         self.threshold = threshold
         self.settle = settle
         self.min_checked = min_checked
+        self.lag = lag
         self._party: set = set()
         self._wire: set = set()
         self._server: set = set()
+        self._reached: dict = {}        # party -> round its chains may
+        self._due: dict = {}            # be judged to; chains awaiting it
         self._checked = 0
         self._complete = 0
         self._fired = False
@@ -379,23 +387,41 @@ class ChainDecayDetector:
         ev = rec.get("ev")
         if ev == "span" and rec.get("name") == "party_round" \
                 and "party" in rec:
-            self._party.add((int(rec["party"]), int(rec["round"])))
-            return []
+            ident = (int(rec["party"]), int(rec["round"]))
+            self._party.add(ident)
+            return self._advance(*ident)
         if ev == "wire" and rec.get("kind") == "c_up" \
                 and not rec.get("observed"):
             sender = str(rec.get("sender", ""))
             if sender.startswith("party:"):
-                self._wire.add((int(sender.split(":", 1)[1]),
-                                int(rec["round"])))
+                ident = (int(sender.split(":", 1)[1]), int(rec["round"]))
+                self._wire.add(ident)
+                return self._advance(*ident)
             return []
         if ev != "span" or rec.get("name") != "server_handle" \
                 or "party" not in rec:
             return []
-        ident = (int(rec["party"]), int(rec["round"]))
-        self._server.add(ident)
-        due = (ident[0], ident[1] - self.settle)
-        if due[1] < 0:
+        m, rnd = int(rec["party"]), int(rec["round"])
+        self._server.add((m, rnd))
+        if rnd >= self.settle:
+            self._due.setdefault(m, []).append(rnd - self.settle)
+        return self._advance(m, rnd - self.lag)
+
+    def _advance(self, m: int, rnd: int) -> List[Alert]:
+        """Let party m's chains be judged up to round ``rnd``; check every
+        due chain of m that is ``settle`` rounds behind that."""
+        reached = self._reached[m] = max(self._reached.get(m, -1), rnd)
+        due = self._due.get(m, [])
+        ready = [d for d in due if d + self.settle <= reached]
+        if not ready:
             return []
+        self._due[m] = [d for d in due if d + self.settle > reached]
+        alerts = []
+        for d in ready:
+            alerts += self._check((m, d))
+        return alerts
+
+    def _check(self, due) -> List[Alert]:
         self._checked += 1
         if due in self._party and due in self._wire and due in self._server:
             self._complete += 1

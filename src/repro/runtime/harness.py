@@ -17,6 +17,11 @@ Processes are started with the multiprocessing 'spawn' context (each
 child gets a fresh jax runtime; fork would inherit locked XLA state) and
 the repo's src dir is forced onto the children's PYTHONPATH so the
 harness works from a bare pytest run as well as an installed package.
+
+Every spawned role runs on ``CHILD_PLATFORM`` (the CPU). The server and
+the parties stand for separate organisations' hosts, and a chip belongs
+to one process at a time: q + 1 children racing the parent for one TPU
+would fail or hang. Each role reports the platform it ran on.
 """
 from __future__ import annotations
 
@@ -40,6 +45,27 @@ from repro.runtime.failures import NO_FAILURES, FailurePlan
 from repro.runtime.party import party_main
 from repro.runtime.problem import build_problem
 from repro.runtime.server import FederationError, server_main
+
+# JAX_PLATFORMS of every spawned server and party process
+CHILD_PLATFORM = "cpu"
+
+
+def export_env(env: dict) -> dict:
+    """Put ``env`` where spawned children inherit it (a None value leaves
+    that variable alone); returns the parent's values for ``restore_env``,
+    which a run calls on exit so its settings never leak into later runs
+    in this interpreter."""
+    prev = {k: os.environ.get(k) for k, v in env.items() if v is not None}
+    os.environ.update({k: env[k] for k in prev})
+    return prev
+
+
+def restore_env(prev: dict) -> None:
+    for k, v in prev.items():
+        if v is None:
+            os.environ.pop(k, None)
+        else:
+            os.environ[k] = v
 
 
 def _ensure_child_pythonpath() -> None:
@@ -78,27 +104,23 @@ def run_federation(spec: dict, rounds: int, *,
     spec = resolve_spec_dp(spec, rounds)
     q = int(spec.get("parties", 2))
     _ensure_child_pythonpath()
-    # trace capture rides the same env-var channel PYTHONPATH does: each
-    # spawned child lazily opens its own trace file on its first
-    # obs.maybe_tracer() call (role = its mp process name); restored in
-    # the finally below so one traced federation can't leak capture into
-    # later runs in this interpreter
-    prev_trace = os.environ.get("REPRO_TRACE_DIR")
-    if cfg.trace_dir:
-        os.environ["REPRO_TRACE_DIR"] = cfg.trace_dir
+    if cfg.monitor and not cfg.trace_dir:
+        raise ValueError("RuntimeConfig.monitor requires trace_dir "
+                         "(the collector writes alerts/health there)")
+    # what every child inherits: its platform, and trace capture (each
+    # child lazily opens its own trace file on its first
+    # obs.maybe_tracer() call, role = its mp process name)
+    prev_env = export_env({"JAX_PLATFORMS": CHILD_PLATFORM,
+                           "REPRO_TRACE_DIR": cfg.trace_dir or None})
     # live health plane: start the collector BEFORE spawning so every
     # child's tracer finds REPRO_MONITOR_ADDR at construction and mirrors
     # its records over the side socket (out-of-band: never a protocol
     # Message, pinned bitwise-invisible in tests)
     monitor = None
-    prev_monitor = os.environ.get(MONITOR_ENV)
     if cfg.monitor:
-        if not cfg.trace_dir:
-            raise ValueError("RuntimeConfig.monitor requires trace_dir "
-                             "(the collector writes alerts/health there)")
         monitor = MonitorServer(cfg.trace_dir,
                                 engine=engine_from_spec(spec, rounds))
-        os.environ[MONITOR_ENV] = monitor.addr
+        prev_env.update(export_env({MONITOR_ENV: monitor.addr}))
     ctx = mp.get_context("spawn")
     port_q = ctx.Queue()
     result_q = ctx.Queue()
@@ -206,16 +228,7 @@ def run_federation(spec: dict, rounds: int, *,
             results["monitor"] = monitor.stop()
         return results
     finally:
-        if cfg.trace_dir:
-            if prev_trace is None:
-                os.environ.pop("REPRO_TRACE_DIR", None)
-            else:
-                os.environ["REPRO_TRACE_DIR"] = prev_trace
-        if monitor is not None:
-            if prev_monitor is None:
-                os.environ.pop(MONITOR_ENV, None)
-            else:
-                os.environ[MONITOR_ENV] = prev_monitor
+        restore_env(prev_env)
         _terminate(list(procs.values()) + [server_proc])
         if monitor is not None:
             monitor.stop()                 # idempotent: error paths too

@@ -28,6 +28,8 @@ from __future__ import annotations
 import os
 import time
 
+import jax
+
 from repro.checkpoint import (available_steps, restore_checkpoint,
                               save_checkpoint)
 from repro.configs.base import RuntimeConfig
@@ -177,6 +179,7 @@ def party_main(spec: dict, m: int, port: int, rounds: int,
 
     result = {
         "party": m,
+        "platform": jax.default_backend(),
         "aborted": aborted,
         "rounds": rounds,
         "bytes_by_kind": dict(channel.bytes_by_kind),

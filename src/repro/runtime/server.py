@@ -43,6 +43,7 @@ import queue
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -510,6 +511,7 @@ class RuntimeServer:
         bytes_by_kind = dict(self.channel.bytes_by_kind)
         transcript = getattr(self.channel, "transcript", None)
         return {
+            "platform": jax.default_backend(),
             "updates": res.updates,
             "history": [(float(t), float(h)) for t, h in res.history],
             "bytes_by_kind": bytes_by_kind,

@@ -30,6 +30,7 @@ import queue as queue_mod
 import socket
 import time
 
+import jax
 import numpy as np
 
 from repro.configs.base import RuntimeConfig
@@ -42,7 +43,8 @@ from repro.obs import MONITOR_ENV, maybe_tracer, trace
 # with the byte-drift detector off.
 from repro.obs.health import HealthEngine
 from repro.obs.monitor import MonitorServer
-from repro.runtime.harness import _ensure_child_pythonpath, _terminate
+from repro.runtime.harness import (CHILD_PLATFORM, _ensure_child_pythonpath,
+                                   _terminate, export_env, restore_env)
 from repro.runtime.problem import build_problem
 from repro.runtime.server import FederationError, make_channel
 from repro.runtime.transport import (ConnectionClosed, FramedSocket,
@@ -119,6 +121,7 @@ def serving_party_main(spec: dict, m: int, port: int, cfg: RuntimeConfig,
 
     result = {
         "party": m,
+        "platform": jax.default_backend(),
         "aborted": aborted,
         "served": served,
         "version": version,
@@ -230,7 +233,8 @@ def run_tcp_serving(spec: dict, sample_ids, *,
 
     Returns {'predictions': [(sample_id, prediction), ...] in submit
     order, 'metrics': engine metrics, 'analytic': validated per-kind wire
-    bytes, 'parties': per-party reports}. When ``ckpt_root`` is given,
+    bytes, 'parties': per-party reports, 'platform': where this front end
+    ran}. When ``ckpt_root`` is given,
     party m restores its newest block from ``<ckpt_root>/party<m>`` (the
     training harness's layout) and its checkpoint step becomes the
     serving params version.
@@ -245,20 +249,20 @@ def run_tcp_serving(spec: dict, sample_ids, *,
     w0 = model.init_server(server_key)
 
     _ensure_child_pythonpath()
-    # same env-var propagation as the training harness: spawned serving
-    # parties lazily open their own trace files when capture is on
-    prev_trace = os.environ.get("REPRO_TRACE_DIR")
-    if cfg.trace_dir:
-        os.environ["REPRO_TRACE_DIR"] = cfg.trace_dir
     monitor = None
-    prev_monitor = os.environ.get(MONITOR_ENV)
     if cfg.monitor:
         if not cfg.trace_dir:
             raise ValueError("RuntimeConfig.monitor requires trace_dir "
                              "(the collector writes alerts/health there)")
         monitor = MonitorServer(cfg.trace_dir,
                                 engine=HealthEngine(byte_drift=False))
-        os.environ[MONITOR_ENV] = monitor.addr
+    # the training harness's child env: parties run on the CPU (this
+    # parent keeps the device for the server engine) and lazily open their
+    # own trace files when capture is on
+    prev_env = export_env({
+        "JAX_PLATFORMS": CHILD_PLATFORM,
+        "REPRO_TRACE_DIR": cfg.trace_dir or None,
+        MONITOR_ENV: monitor.addr if monitor is not None else None})
     ctx = mp.get_context("spawn")
     result_q = ctx.Queue()
 
@@ -309,6 +313,7 @@ def run_tcp_serving(spec: dict, sample_ids, *,
             p.join(timeout=10.0)
         by_rid = sorted(completed, key=lambda r: r.rid)
         out = {
+            "platform": jax.default_backend(),
             "predictions": [(r.sample_id, r.prediction) for r in by_rid],
             "metrics": engine.metrics(),
             "analytic": analytic,
@@ -318,16 +323,7 @@ def run_tcp_serving(spec: dict, sample_ids, *,
             out["monitor"] = monitor.stop()
         return out
     finally:
-        if cfg.trace_dir:
-            if prev_trace is None:
-                os.environ.pop("REPRO_TRACE_DIR", None)
-            else:
-                os.environ["REPRO_TRACE_DIR"] = prev_trace
-        if monitor is not None:
-            if prev_monitor is None:
-                os.environ.pop(MONITOR_ENV, None)
-            else:
-                os.environ[MONITOR_ENV] = prev_monitor
+        restore_env(prev_env)
         server_sock.close()
         if engine is not None:
             engine.close()

@@ -228,6 +228,29 @@ def test_chain_decay_settles_then_fires_below_threshold():
     assert alerts[0].value < 0.95
 
 
+def test_chain_decay_ignores_the_order_streams_are_drained():
+    """The collector may drain the server's whole backlog before a
+    party's: complete chains stay silent whichever stream comes first."""
+    det = ChainDecayDetector(threshold=0.95, settle=2, min_checked=5)
+    chains = [_chain(m, rnd) for m in (0, 1) for rnd in range(8)]
+    server = [c[2] for c in chains]
+    party = [r for c in chains for r in c[:2]]
+    assert _feed(det, server + party) == []
+    assert det._checked == 2 * (8 - 2)
+    assert det._complete == det._checked
+
+
+def test_chain_decay_fires_when_a_party_stream_goes_silent():
+    """A party whose records never arrive has its chains judged once the
+    server is ``lag`` rounds on."""
+    det = ChainDecayDetector(threshold=0.95, settle=2, min_checked=5, lag=8)
+    alerts = _feed(det, [_chain(0, rnd)[2] for rnd in range(30)])
+    assert len(alerts) == 1
+    assert alerts[0].value == 0.0
+    # the fifth chain checked (min_checked), judged at server round 14
+    assert (alerts[0].party, alerts[0].round) == (0, 4)
+
+
 # --------------------------------------------- engine / spec wiring -------
 
 def _dp_detector(engine):

@@ -234,7 +234,6 @@ def test_sharded_int8_rounding_independent_per_shard():
     identical per-shard payloads under the replicated step key must NOT
     share one stochastic-rounding draw (the per-direction independence
     fix, applied along the shard axis)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = jax.device_count()
@@ -246,8 +245,8 @@ def test_sharded_int8_rounding_independent_per_shard():
     def body(cs):
         return ex.roundtrip_up(cs, jax.random.key(0))
 
-    out = shard_map(body, mesh=mesh, in_specs=P("data"),
-                    out_specs=P("data"), check_rep=False)(
+    out = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                        out_specs=P("data"), check_vma=False)(
         jnp.tile(c, (dp,)))
     shards = np.asarray(out).reshape(dp, -1)
     for r in range(1, dp):

@@ -76,11 +76,12 @@ def test_train_launcher_smoke(tmp_path):
 @pytest.mark.slow
 def test_train_launcher_vfl_zoo_smoke():
     from repro.launch import train as train_mod
-    loss = train_mod.main([
+    run = train_mod.main([
         "--arch", "qwen1.5-0.5b", "--reduced", "--steps", "6",
         "--batch-size", "2", "--seq-len", "16", "--mode", "vfl-zoo",
         "--parties", "4"])
-    assert np.isfinite(loss)
+    assert len(run.losses) == len(run.step_s) == 6
+    assert np.isfinite(run.losses).all()
 
 
 def test_serve_launcher_smoke():
