@@ -524,12 +524,14 @@ def _dispatch(args, cfg):
 class ZooRun(NamedTuple):
     """What an in-process vfl-zoo run leaves: the final AsyREVEL state,
     the per-step server losses h, per-step wall seconds, each taken
-    around ``block_until_ready`` (step 0 includes the compile), and the
-    jitted step it called."""
+    around ``block_until_ready`` (step 0 includes the compile), the
+    jitted step it called, and ``init``, which rebuilds the state a run
+    without ``--resume`` starts from."""
     state: AsyState
     losses: list
     step_s: list
     step: Callable
+    init: Callable[[], AsyState]
 
 
 def _model_and_data(args, cfg):
@@ -649,7 +651,7 @@ def run_vfl_zoo(args, cfg, mesh=None) -> ZooRun:
                         {"w0": state.w0, "parties": state.parties,
                          "hist": state.hist},
                         {"arch": args.arch, "mode": "vfl-zoo"})
-    return ZooRun(state, losses, step_s, zoo_step)
+    return ZooRun(state, losses, step_s, zoo_step, lambda: init(key))
 
 
 if __name__ == "__main__":

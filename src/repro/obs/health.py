@@ -362,18 +362,18 @@ class ChainDecayDetector:
     one side may run many rounds ahead of the other; waiting for both
     keeps the verdict independent of that order. A party whose stream
     went silent (its mirror link dropped) has its chains checked once the
-    server is ``lag`` rounds past them. Fires when the running
+    server is ``LAG`` rounds past them. Fires when the running
     completeness over at least ``min_checked`` chains decays below
     ``threshold`` (95% is the acceptance gate); re-arms on recovery."""
 
     name = "chain_decay"
+    LAG = 32
 
     def __init__(self, threshold: float = 0.95, settle: int = 2,
-                 min_checked: int = 5, lag: int = 32):
+                 min_checked: int = 5):
         self.threshold = threshold
         self.settle = settle
         self.min_checked = min_checked
-        self.lag = lag
         self._party: set = set()
         self._wire: set = set()
         self._server: set = set()
@@ -405,7 +405,7 @@ class ChainDecayDetector:
         self._server.add((m, rnd))
         if rnd >= self.settle:
             self._due.setdefault(m, []).append(rnd - self.settle)
-        return self._advance(m, rnd - self.lag)
+        return self._advance(m, rnd - self.LAG)
 
     def _advance(self, m: int, rnd: int) -> List[Alert]:
         """Let party m's chains be judged up to round ``rnd``; check every

@@ -16,12 +16,17 @@ import jax
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
 # src/repro/utils/compile_cache.py -> the checkout root holding src/
-REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+_SRC = Path(__file__).resolve().parents[2]
+REPO_CACHE_DIR = _SRC.parent / ".jax_cache"
 
 
 def enable_compile_cache() -> str:
     """Point JAX's persistent cache at its fixed directory; returns it."""
     if os.environ.get(ENV):
         return jax.config.jax_compilation_cache_dir
+    if _SRC.name != "src" or not (_SRC.parent / "pyproject.toml").is_file():
+        raise RuntimeError(
+            f"repro is not imported from a checkout ({_SRC}), so there is "
+            f"no <checkout>/.jax_cache; set {ENV} to place the cache")
     jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
     return str(REPO_CACHE_DIR)

@@ -68,6 +68,26 @@ def test_phase_chip_vs_cpu_reduced():
     chip_smoke.phase_chip_vs_cpu(REDUCED)
 
 
+def test_update_gap_sees_a_step_that_applied_no_update():
+    """The losses of a run that never applied its update stay within the
+    loss bound; the update gap reads 1 in every block the run moved (w0
+    and the parties it activated) and fails the check."""
+    from repro.launch import train
+
+    run = train.main(REDUCED)
+    h, moves = np.asarray(run.losses), chip_smoke.param_moves(run)
+    moved = {k for k, v in moves.items() if np.linalg.norm(v) > 0}
+    assert "w0" in moved and len(moved) > 1
+    bounds = chip_smoke.CHIP_VS_CPU["highest"]
+    chip_smoke._hold("t", "itself", (h, moves), (h, moves), **bounds)
+    frozen = {k: np.zeros_like(v) for k, v in moves.items()}
+    assert chip_smoke.update_gaps(frozen, moves) == {
+        k: float(k in moved) for k in moves}
+    with pytest.raises(chip_smoke.SmokeFailure, match="update gap"):
+        chip_smoke._hold("t", "no update", (h, frozen), (h, moves),
+                         **bounds)
+
+
 def test_phase_paper_lr_reduced():
     chip_smoke.phase_paper_lr(scale=0.1, steps=500)
 
@@ -114,6 +134,17 @@ def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch,
     assert got == str(ROOT / ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == got
     assert compile_cache.enable_compile_cache() == got     # never moves
+
+
+def test_compile_cache_refuses_a_place_outside_a_checkout(
+        monkeypatch, restore_cache_dir, tmp_path):
+    """Imported from an installed package there is no checkout to hold
+    the cache: the helper asks for the variable instead of writing into
+    the Python installation."""
+    monkeypatch.delenv(compile_cache.ENV, raising=False)
+    monkeypatch.setattr(compile_cache, "_SRC", tmp_path / "site-packages")
+    with pytest.raises(RuntimeError, match=compile_cache.ENV):
+        compile_cache.enable_compile_cache()
 
 
 def test_compile_cache_dir_is_ignored_by_git():

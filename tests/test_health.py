@@ -242,12 +242,13 @@ def test_chain_decay_ignores_the_order_streams_are_drained():
 
 def test_chain_decay_fires_when_a_party_stream_goes_silent():
     """A party whose records never arrive has its chains judged once the
-    server is ``lag`` rounds on."""
-    det = ChainDecayDetector(threshold=0.95, settle=2, min_checked=5, lag=8)
-    alerts = _feed(det, [_chain(0, rnd)[2] for rnd in range(30)])
+    server is ``LAG`` rounds on."""
+    det = ChainDecayDetector(threshold=0.95, settle=2, min_checked=5)
+    alerts = _feed(det, [_chain(0, rnd)[2] for rnd in range(60)])
     assert len(alerts) == 1
     assert alerts[0].value == 0.0
-    # the fifth chain checked (min_checked), judged at server round 14
+    # the fifth chain checked (min_checked), judged at server round
+    # 4 + settle + LAG = 38
     assert (alerts[0].party, alerts[0].round) == (0, 4)
 
 
