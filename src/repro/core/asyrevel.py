@@ -93,18 +93,21 @@ def asyrevel_step(model: VFLModel, vfl: VFLConfig, state: AsyState, batch,
     # w^{t-delta} = params after step t-1-delta; hist[s] holds the params
     # written at the end of the latest step with step % (tau+1) == s.
     slots = (state.step - 1 - delays) % (tau + 1)
-    stale = _stale_parties(state.hist, slots)
+    with jax.named_scope("ring_buffer"):
+        stale = _stale_parties(state.hist, slots)
 
     # --- step 4-5: party m computes c_m, c_hat_m on PRIVATE data; the c
     # table the server holds is what survived the up-link codec, one
     # MESSAGE (party) at a time — each party's upload is its own tensor
     # with its own codec scale, matching the host executor's wire --------
-    cs = model.all_party_outputs(stale, x)                  # stale c's
+    with jax.named_scope("party_forward"):
+        cs = model.all_party_outputs(stale, x)              # stale c's
     cs = model.map_party_outputs(
         cs, lambda c, m: ex.roundtrip_up(c, jax.random.fold_in(k_c, m)))
     w_m = _gather_party(state.parties, m_t)
     x_m = model.slice_features(x, m_t)
-    h = model.server_forward(state.w0, cs, y)               # h_{i,m}
+    with jax.named_scope("server_forward"):
+        h = model.server_forward(state.w0, cs, y)           # h_{i,m}
     reg0 = model.regularizer(w_m)
 
     # one or several directions (num_directions > 1 = variance-reduced
@@ -116,10 +119,12 @@ def asyrevel_step(model: VFLModel, vfl: VFLConfig, state: AsyState, batch,
     # stochastic-rounding draw (shared noise would defeat the K-direction
     # variance reduction).
     def f_of(w_m_pert, k_dir):
-        c_hat = model.party_forward(w_m_pert, x_m, m_t)
+        with jax.named_scope("party_forward"):
+            c_hat = model.party_forward(w_m_pert, x_m, m_t)
         c_hat = ex.roundtrip_up(c_hat, fold_name(k_dir, "codec_hat"))
         cs_hat = model.replace_party_output(cs, c_hat, m_t)
-        h_bar = model.server_forward(state.w0, cs_hat, y)   # h-bar_{i,m}
+        with jax.named_scope("server_forward"):
+            h_bar = model.server_forward(state.w0, cs_hat, y)  # h-bar_{i,m}
         return h_bar + vfl.lam * model.regularizer(w_m_pert)
 
     g_m = ex.party_gradient(w_m, k_u, h + vfl.lam * reg0, f_of)
@@ -128,17 +133,19 @@ def asyrevel_step(model: VFLModel, vfl: VFLConfig, state: AsyState, batch,
     parties = ex.apply_block(state.parties, m_t, g_m, vfl.lr_party)
 
     # --- step 9-11: server's own estimate + update (Eq. 17) ---------------
+    def h_hat_of(w0p):                                      # h-hat_{i,m}
+        with jax.named_scope("server_forward"):
+            return model.server_forward(w0p, cs, y)
+
     if vfl.perturb_server:
-        w0 = ex.server_update(
-            state.w0, k_u0, h,
-            lambda w0p: model.server_forward(w0p, cs, y),   # h-hat_{i,m}
-            vfl.lr_server)
+        w0 = ex.server_update(state.w0, k_u0, h, h_hat_of, vfl.lr_server)
     else:
         w0 = state.w0
 
-    hist = jax.tree.map(
-        lambda hbuf, p: hbuf.at[state.step % (tau + 1)].set(p),
-        state.hist, parties)
+    with jax.named_scope("ring_buffer"):
+        hist = jax.tree.map(
+            lambda hbuf, p: hbuf.at[state.step % (tau + 1)].set(p),
+            state.hist, parties)
     new_state = AsyState(w0, parties, hist, state.step + 1, state.key)
     return new_state, h
 
@@ -153,10 +160,12 @@ def synrevel_step(model: VFLModel, vfl: VFLConfig, state: AsyState, batch,
     k_c = fold_name(key, "codec")
     x = model.party_args(batch)
     y = model.server_args(batch)
-    cs = model.all_party_outputs(state.parties, x)
+    with jax.named_scope("party_forward"):
+        cs = model.all_party_outputs(state.parties, x)
     cs = model.map_party_outputs(
         cs, lambda c, m: ex.roundtrip_up(c, jax.random.fold_in(k_c, m)))
-    h = model.server_forward(state.w0, cs, y)
+    with jax.named_scope("server_forward"):
+        h = model.server_forward(state.w0, cs, y)
 
     new_parties = state.parties
     for m in range(q):
@@ -164,23 +173,28 @@ def synrevel_step(model: VFLModel, vfl: VFLConfig, state: AsyState, batch,
         w_m = _gather_party(state.parties, m)
 
         def f_of(w_m_pert, k_dir, m=m):
-            c_hat = model.party_forward(
-                w_m_pert, model.slice_features(x, m), m)
+            with jax.named_scope("party_forward"):
+                c_hat = model.party_forward(
+                    w_m_pert, model.slice_features(x, m), m)
             # k_dir already encodes the party (derived from k_u) AND the
             # direction, so every upload gets its own rounding draw
             c_hat = ex.roundtrip_up(c_hat, fold_name(k_dir, "codec_hat"))
-            h_bar = model.server_forward(
-                state.w0, model.replace_party_output(cs, c_hat, m), y)
+            cs_hat = model.replace_party_output(cs, c_hat, m)
+            with jax.named_scope("server_forward"):
+                h_bar = model.server_forward(state.w0, cs_hat, y)
             return h_bar + vfl.lam * model.regularizer(w_m_pert)
 
         g_m = ex.party_gradient(
             w_m, k_u, h + vfl.lam * model.regularizer(w_m), f_of)
         new_parties = ex.apply_block(new_parties, m, g_m, vfl.lr_party)
 
+    def h_hat_of(w0p):
+        with jax.named_scope("server_forward"):
+            return model.server_forward(w0p, cs, y)
+
     if vfl.perturb_server:
-        w0 = ex.server_update(
-            state.w0, fold_name(key, "u0"), h,
-            lambda w0p: model.server_forward(w0p, cs, y), vfl.lr_server)
+        w0 = ex.server_update(state.w0, fold_name(key, "u0"), h, h_hat_of,
+                              vfl.lr_server)
     else:
         w0 = state.w0
     new_state = AsyState(w0, new_parties, state.hist, state.step + 1,
@@ -204,7 +218,8 @@ def train(model: VFLModel, vfl: VFLConfig, data, key, steps: int,
 
     def body(state, k):
         idx = jax.random.randint(k, (batch_size,), 0, n)
-        batch = jax.tree.map(lambda a: a[idx], data)
+        with jax.named_scope("batch_gather"):
+            batch = jax.tree.map(lambda a: a[idx], data)
         return step_fn(model, vfl, state, batch, ex)
 
     keys = jax.random.split(jax.random.fold_in(key, 7), steps)
@@ -330,7 +345,8 @@ def make_sharded_train_fn(model: VFLModel, vfl: VFLConfig, n: int,
                 r = jax.lax.axis_index(data_axis)
                 idx = jax.lax.dynamic_slice_in_dim(
                     idx, r * local_b, local_b)
-                batch = jax.tree.map(lambda a: a[idx], data)
+                with jax.named_scope("batch_gather"):
+                    batch = jax.tree.map(lambda a: a[idx], data)
                 return step_fn(pmodel, vfl, state, batch, ex)
 
             return jax.lax.scan(body, state, keys)

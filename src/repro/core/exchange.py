@@ -285,6 +285,7 @@ class ZOExchange:
         from repro.dp.mechanisms import defend_payload
         return defend_payload(c, self._dp_key(key), self.dp)
 
+    @jax.named_scope("exchange_up")
     def encode_up(self, c, key=None):
         """Party side: function values -> wire payload (+ measured bytes).
         The DP defense (clip-then-noise, repro/dp) applies HERE, before
@@ -304,6 +305,7 @@ class ZOExchange:
         """Server side: wire payload -> the f32 values F_0 consumes."""
         return self.codec.decode(wire)
 
+    @jax.named_scope("exchange_up")
     def roundtrip_up(self, c, key=None):
         """What the server sees after the up-link (identity for f32 with
         dp off) — the jit-traced twin of encode_up + decode_up."""
@@ -322,6 +324,7 @@ class ZOExchange:
         return fvals if len(fvals) > 1 else fvals[0]
 
     # ---- estimator math (Eqs. 14-15) -------------------------------------
+    @jax.named_scope("zo_perturb")
     def perturb(self, w, key):
         """w + mu * u. Returns (perturbed_tree, u_tree)."""
         if self.fused and self.direction == "rademacher":
@@ -356,23 +359,28 @@ class ZOExchange:
             # at the update site (fused-kernel path on TPU).
             w_p, _ = self.perturb(w_m, key)
             coeff = self.coefficient(f_of(w_p, key), f_base)
-            if self.fused and self.direction == "rademacher":
-                return fused_round.zo_gradient_from_seed(w_m, key, coeff)
-            return zoo.zo_gradient_from_seed(key, w_m, self.direction, coeff)
+            with jax.named_scope("zo_update"):
+                if self.fused and self.direction == "rademacher":
+                    return fused_round.zo_gradient_from_seed(w_m, key, coeff)
+                return zoo.zo_gradient_from_seed(key, w_m, self.direction,
+                                                 coeff)
         if K == 1:
             w_p, u = self.perturb(w_m, key)
             coeff = self.coefficient(f_of(w_p, key), f_base)
-            return zoo.zo_gradient(u, coeff)
+            with jax.named_scope("zo_update"):
+                return zoo.zo_gradient(u, coeff)
         keys = jax.random.split(key, K)
         w_ps, us = jax.vmap(lambda k: self.perturb(w_m, k))(keys)
         coeffs = jax.vmap(
             lambda f: self.coefficient(f, f_base))(jax.vmap(f_of)(w_ps, keys))
-        return jax.tree.map(
-            lambda u: jnp.mean(
-                coeffs.reshape((K,) + (1,) * (u.ndim - 1)) * u, axis=0),
-            us)
+        with jax.named_scope("zo_update"):
+            return jax.tree.map(
+                lambda u: jnp.mean(
+                    coeffs.reshape((K,) + (1,) * (u.ndim - 1)) * u, axis=0),
+                us)
 
     # ---- update apply (Algorithm 1 line 7 / Eq. 15) ----------------------
+    @jax.named_scope("zo_update")
     def apply_block(self, stacked, m, g, lr: float):
         """In-place-style block-coordinate update of party m inside the
         stacked (q, ...) parameter tree."""
@@ -380,6 +388,7 @@ class ZOExchange:
             lambda a, gg: a.at[m].add((-lr * gg).astype(a.dtype)),
             stacked, g)
 
+    @jax.named_scope("zo_update")
     def apply_direction(self, w, u, coeff, lr: float):
         """Dense update from a materialized direction: w - lr * coeff * u."""
         if self.fused:
@@ -387,6 +396,7 @@ class ZOExchange:
         return jax.tree.map(
             lambda a, d: (a - lr * coeff * d).astype(a.dtype), w, u)
 
+    @jax.named_scope("zo_update")
     def apply_from_seed(self, w, key, coeff, lr: float):
         """Seed-replay update: regenerate u from ``key``; never store it."""
         if self.fused and self.direction == "rademacher":
@@ -394,6 +404,7 @@ class ZOExchange:
                 w, key, jnp.asarray(lr * coeff, jnp.float32))
         return zoo.apply_zo_update(w, key, self.direction, coeff, lr)
 
+    @jax.named_scope("zo_update")
     def apply_fused(self, w, key, coeff, lr: float, *,
                     impl: str = "pallas", interpret: bool | None = None):
         """Fused kernels path (Rademacher directions only): the per-leaf
@@ -414,9 +425,10 @@ class ZOExchange:
         re-evaluates F_0 on the SAME received c table — no extra up-link."""
         w0p, u0 = self.perturb(w0, key)
         coeff = self.coefficient(f_of(w0p), f_base)
-        g0 = zoo.zo_gradient(u0, coeff)
-        return jax.tree.map(
-            lambda a, g: (a - lr * g).astype(a.dtype), w0, g0)
+        with jax.named_scope("zo_update"):
+            g0 = zoo.zo_gradient(u0, coeff)
+            return jax.tree.map(
+                lambda a, g: (a - lr * g).astype(a.dtype), w0, g0)
 
     # ---- accounting -------------------------------------------------------
     def round_comms(self, c) -> RoundComms:

@@ -40,7 +40,7 @@ from repro.runtime.failures import CRASH_EXIT_CODE, PartyFault
 from repro.runtime.problem import build_problem
 from repro.runtime.transport import (ConnectionClosed, FramedSocket,
                                      TransportError, TransportTimeout,
-                                     connect_with_retry)
+                                     byte_counts, connect_with_retry)
 
 
 def _recv_reply(fsock: FramedSocket, cfg: RuntimeConfig, peer="server"):
@@ -185,8 +185,7 @@ def party_main(spec: dict, m: int, port: int, rounds: int,
         "bytes_by_kind": dict(channel.bytes_by_kind),
         "msgs_by_kind": dict(channel.msgs_by_kind),
         "up_bytes": ex.meter.up_bytes,
-        "socket_bytes_out": fsock.bytes_out,
-        "socket_bytes_in": fsock.bytes_in,
+        **byte_counts([fsock]),
         "final_w": {k: np.asarray(v) for k, v in w_m.items()},
     }
     tr = maybe_tracer()

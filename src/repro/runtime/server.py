@@ -57,7 +57,8 @@ from repro.core.wire import (InMemoryChannel, NetworkChannel,
 from repro.obs import maybe_tracer, trace
 from repro.runtime.problem import build_problem
 from repro.runtime.transport import (ConnectionClosed, FramedSocket,
-                                     TransportError, TransportTimeout)
+                                     TransportError, TransportTimeout,
+                                     byte_counts)
 
 
 class FederationError(RuntimeError):
@@ -126,8 +127,7 @@ class RuntimeServer:
         # staleness actually admitted to processing
         self._parked_events = 0
         self._staleness_max = 0
-        self._dead_bytes_in = 0
-        self._dead_bytes_out = 0
+        self._dead_socks: list[FramedSocket] = []
         self._listener: FramedSocket | None = None
         if resume and ckpt_dir is not None:
             self._restore()
@@ -230,8 +230,7 @@ class RuntimeServer:
                 if prev is not None:
                     # keep the dead link's measured socket traffic in the
                     # run totals before the rejoin replaces it
-                    self._dead_bytes_in += prev.fsock.bytes_in
-                    self._dead_bytes_out += prev.fsock.bytes_out
+                    self._dead_socks.append(prev.fsock)
                 self._links[m] = _PartyLink(fsock, seq)
             # one consistent (updates, processed) cut: the dispatcher
             # advances both inside _process's critical section, and a
@@ -526,10 +525,8 @@ class RuntimeServer:
             "staleness_max": self._staleness_max,
             "processed": processed,
             "w0": w0,
-            "socket_bytes_in": self._dead_bytes_in + sum(
-                link.fsock.bytes_in for link in links),
-            "socket_bytes_out": self._dead_bytes_out + sum(
-                link.fsock.bytes_out for link in links),
+            **byte_counts(self._dead_socks
+                          + [link.fsock for link in links]),
         }
 
 

@@ -49,7 +49,7 @@ from repro.runtime.problem import build_problem
 from repro.runtime.server import FederationError, make_channel
 from repro.runtime.transport import (ConnectionClosed, FramedSocket,
                                      TransportError, TransportTimeout,
-                                     connect_with_retry)
+                                     byte_counts, connect_with_retry)
 from repro.serving.federated import (FederatedServingEngine, ServeRequest,
                                      answer_serve_query)
 
@@ -127,8 +127,7 @@ def serving_party_main(spec: dict, m: int, port: int, cfg: RuntimeConfig,
         "version": version,
         "bytes_by_kind": dict(channel.bytes_by_kind),
         "msgs_by_kind": dict(channel.msgs_by_kind),
-        "socket_bytes_out": fsock.bytes_out,
-        "socket_bytes_in": fsock.bytes_in,
+        **byte_counts([fsock]),
     }
     tr = maybe_tracer()
     if tr is not None:

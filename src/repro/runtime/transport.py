@@ -259,7 +259,14 @@ def decode_message(body) -> Message:
 class FramedSocket:
     """Length-prefixed framing over one TCP connection, with write
     serialization (pong replies and protocol replies may come from
-    different threads) and measured socket-byte counters."""
+    different threads) and measured socket-byte counters.
+
+    ``bytes_out``/``bytes_in`` count everything that crossed the socket;
+    ``control_bytes_out``/``control_bytes_in`` count the whole frames of
+    the control channel (hello/welcome/ping/pong/bye) among them. How
+    many pings a party sends depends on timing (one after each
+    ``heartbeat_s`` of silence), so only the rest, the protocol frames,
+    is a function of the run's seed (``byte_counts``)."""
 
     def __init__(self, sock: socket.socket):
         try:
@@ -271,6 +278,8 @@ class FramedSocket:
         self.sock = sock
         self.bytes_out = 0
         self.bytes_in = 0
+        self.control_bytes_out = 0
+        self.control_bytes_in = 0
         self._wlock = threading.Lock()
         # bytes of a partially-received frame survive a timeout here, so
         # a caller may retry recv() without desynchronizing the stream
@@ -285,6 +294,8 @@ class FramedSocket:
             except OSError as e:
                 raise ConnectionClosed(f"send failed: {e}") from e
             self.bytes_out += len(frame)
+            if frame_type == FRAME_CONTROL:
+                self.control_bytes_out += len(frame)
 
     def send_message(self, msg: Message) -> int:
         body = encode_message(msg)
@@ -325,6 +336,7 @@ class FramedSocket:
         if frame_type == FRAME_MESSAGE:
             return "msg", decode_message(body[1:])
         if frame_type == FRAME_CONTROL:
+            self.control_bytes_in += 4 + size
             return "ctl", json.loads(body[1:].decode("utf-8"))
         raise WireFormatError(f"unknown frame type {frame_type}")
 
@@ -333,6 +345,20 @@ class FramedSocket:
             self.sock.close()
         except OSError:
             pass
+
+
+def byte_counts(fsocks) -> dict:
+    """The measured traffic of ``fsocks`` for a run's result: every
+    socket byte, and the protocol frames' bytes alone (the control
+    frames left out), each way."""
+    out = {"socket_bytes_in": 0, "socket_bytes_out": 0,
+           "protocol_bytes_in": 0, "protocol_bytes_out": 0}
+    for f in fsocks:
+        out["socket_bytes_in"] += f.bytes_in
+        out["socket_bytes_out"] += f.bytes_out
+        out["protocol_bytes_in"] += f.bytes_in - f.control_bytes_in
+        out["protocol_bytes_out"] += f.bytes_out - f.control_bytes_out
+    return out
 
 
 def connect_with_retry(host: str, port: int, retries: int = 40,

@@ -17,6 +17,7 @@ from repro.core.wire import SERVER, Message, RecordingChannel, party
 from repro.runtime import (FailurePlan, PartyFault, TransportTimeout,
                            FramedSocket, history_losses, run_federation,
                            run_reference)
+from repro.runtime.transport import byte_counts, encode_message
 
 runtime = pytest.mark.runtime
 slow = pytest.mark.slow
@@ -61,6 +62,26 @@ def test_framed_socket_roundtrips_messages_and_controls():
     a.close(), b.close()
 
 
+def test_framed_socket_counts_control_frames_apart():
+    a, b = _socketpair()
+    msg = Message.make("loss_down", SERVER, party(1), 3, (0.5, 0.25))
+    for _ in range(3):
+        a.send_control({"type": "ping"})
+    a.send_message(msg)
+    a.send_control({"type": "bye"})
+    kinds = [b.recv(timeout=5.0)[0] for _ in range(5)]
+    assert kinds == ["ctl"] * 3 + ["msg", "ctl"]
+    ctl = 3 * (5 + len(b'{"type": "ping"}')) + 5 + len(b'{"type": "bye"}')
+    assert a.control_bytes_out == b.control_bytes_in == ctl
+    # the protocol frames alone: the message's frame, whatever the pings
+    sent, got = byte_counts([a]), byte_counts([b])
+    assert sent["protocol_bytes_out"] == got["protocol_bytes_in"] == \
+        len(encode_message(msg)) + 5
+    assert sent["socket_bytes_out"] == got["socket_bytes_in"] == \
+        a.bytes_out
+    a.close(), b.close()
+
+
 def test_framed_socket_timeout_is_typed():
     a, b = _socketpair()
     with pytest.raises(TransportTimeout):
@@ -71,7 +92,6 @@ def test_framed_socket_timeout_is_typed():
 def test_recv_survives_mid_frame_timeout():
     """A timeout with a frame partially received must not desynchronize
     the stream: the retried recv() resumes the SAME frame."""
-    from repro.runtime.transport import encode_message
     a, b = _socketpair()
     msg = Message.make("c_up", party(0), SERVER, 0,
                        np.arange(16, dtype=np.float32))
