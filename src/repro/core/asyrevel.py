@@ -50,13 +50,6 @@ def _gather_party(tree, m):
     return jax.tree.map(lambda a: a[m], tree)
 
 
-def _stale_parties(hist, slots):
-    """hist leaves: (tau+1, q, ...); slots: (q,) int -> (q, ...) params."""
-    q = slots.shape[0]
-    return jax.tree.map(
-        lambda h: h[slots, jnp.arange(q)], hist)
-
-
 def init_state(model: VFLModel, vfl: VFLConfig, key) -> AsyState:
     k0, k1 = jax.random.split(key)
     w0 = model.init_server(k0)
@@ -93,15 +86,12 @@ def asyrevel_step(model: VFLModel, vfl: VFLConfig, state: AsyState, batch,
     # w^{t-delta} = params after step t-1-delta; hist[s] holds the params
     # written at the end of the latest step with step % (tau+1) == s.
     slots = (state.step - 1 - delays) % (tau + 1)
-    with jax.named_scope("ring_buffer"):
-        stale = _stale_parties(state.hist, slots)
 
     # --- step 4-5: party m computes c_m, c_hat_m on PRIVATE data; the c
     # table the server holds is what survived the up-link codec, one
     # MESSAGE (party) at a time — each party's upload is its own tensor
     # with its own codec scale, matching the host executor's wire --------
-    with jax.named_scope("party_forward"):
-        cs = model.all_party_outputs(stale, x)              # stale c's
+    cs = model.stale_party_outputs(state.hist, slots, x)    # stale c's
     cs = model.map_party_outputs(
         cs, lambda c, m: ex.roundtrip_up(c, jax.random.fold_in(k_c, m)))
     w_m = _gather_party(state.parties, m_t)

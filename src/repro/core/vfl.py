@@ -149,6 +149,18 @@ class VFLModel:
         return jnp.stack([one(m, jax.tree.map(lambda a: a[m], stacked_w))
                           for m in range(self.num_parties)], axis=1)
 
+    def stale_party_outputs(self, hist, slots, x):
+        """c_m for every party from its DELAYED params: party m reads the
+        ring buffer's slot slots[m]. hist leaves: (tau+1, q, ...); slots:
+        (q,) int. Gathers each party's whole stale params; a model whose
+        party leaf is a lookup table read only at the batch's rows
+        overrides this to read just those rows."""
+        q = slots.shape[0]
+        with jax.named_scope("ring_buffer"):
+            stale = jax.tree.map(lambda h: h[slots, jnp.arange(q)], hist)
+        with jax.named_scope("party_forward"):
+            return self.all_party_outputs(stale, x)
+
     def full_loss(self, w0, stacked_w, x, y, lam: float):
         """Centralized view of problem (P) — used by NonF baseline & tests."""
         cs = self.all_party_outputs(stacked_w, x)
@@ -276,15 +288,37 @@ class TransformerVFLModel(VFLModel):
     def slice_features(self, x, m: int):
         return x        # tokens are shared ids; the SLICE is the embedding
 
-    def party_forward(self, w_m, tokens, m: int):
-        e = w_m["embed"][tokens]                        # (B,S,dq)
+    @staticmethod
+    def _tower(w_m, e):
         h = jax.nn.gelu(e @ w_m["w1"])
         return e + h @ w_m["w2"]                        # residual tower
+
+    def party_forward(self, w_m, tokens, m: int):
+        return self._tower(w_m, w_m["embed"][tokens])   # e: (B,S,dq)
 
     def all_party_outputs(self, stacked_w, tokens):
         def one(w_m):
             return self.party_forward(w_m, tokens, 0)
         cs = jax.vmap(one)(stacked_w)                   # (q,B,S,dq)
+        return jnp.moveaxis(cs, 0, -2)                  # (B,S,q,dq)
+
+    def stale_party_outputs(self, hist, slots, tokens):
+        """The stale c's reading only the embedding rows the batch uses,
+        straight out of the ring buffer: gathering whole stale tables
+        first would move every party's vocab x dq slice each round. The
+        buffer flattens to one (slot, party, token)-row table, as the
+        server's own embedding lookup reads its table; the small tower
+        leaves are gathered whole."""
+        emb = hist["embed"]                             # (tau+1,q,V,dq)
+        n, q, vocab, dq = emb.shape
+        with jax.named_scope("ring_buffer"):
+            base = (slots * q + jnp.arange(q)) * vocab  # (q,)
+            e = emb.reshape(n * q * vocab, dq)[
+                base[:, None, None] + tokens[None]]     # (q,B,S,dq)
+            towers = {k: h[slots, jnp.arange(q)]
+                      for k, h in hist.items() if k != "embed"}
+        with jax.named_scope("party_forward"):
+            cs = jax.vmap(self._tower)(towers, e)       # (q,B,S,dq)
         return jnp.moveaxis(cs, 0, -2)                  # (B,S,q,dq)
 
     def replace_party_output(self, cs, c_new, m):

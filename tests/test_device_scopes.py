@@ -1,7 +1,7 @@
 """The phases of an AsyREVEL round are named on the device.
 
-``core/asyrevel.py`` and ``core/exchange.py`` name Algorithm 1's steps
-with ``jax.named_scope``; the chip benchmark reads each device op's phase
+``core/asyrevel.py``, ``core/exchange.py`` and the models' stale read
+(``core/vfl.py``) name Algorithm 1's steps with ``jax.named_scope``; the chip benchmark reads each device op's phase
 from the op_name that XLA keeps in the compiled program
 (``chipbench/scopes.py``). Each name it reads must reach the compiled HLO
 of the two stepping paths it measures, the vfl-zoo step and the sharded
