@@ -33,7 +33,7 @@ def main(argv=None):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     jax.config.update("jax_enable_compilation_cache", False)
-    from chipbench.drivers.zoo_step import model_config, vfl_config
+    from chipbench.drivers.zoo_step import vfl_config
     from repro.launch import steps as step_lib
     from repro.models import build_model
 
@@ -45,7 +45,8 @@ def main(argv=None):
                                         topology_name="v5e:2x2")
     mesh = Mesh(topo.devices[:n], ("data",))
     _, init, step = step_lib.make_vfl_zoo_step(
-        build_model(model_config(cfg)), vfl_config(cfg),
+        build_model(common.load_module("families", cfg["family"])
+                    .program_config(cfg)), vfl_config(cfg),
         mesh=mesh if n > 1 else None)
     rep = NamedSharding(mesh, P())
     state = jax.tree.map(

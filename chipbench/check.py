@@ -15,6 +15,15 @@ its own. A change is a state minus the start state.
   the reference follows.
 - ``change_median``: the median leaf's gap of the same kind as
   ``change_gap``: steadier from seed to seed than the worst leaf's.
+- ``value_gap``: the largest gap, in loss per mu, among the values a
+  zeroth-order round is computed from: the first round's loss over mu,
+  and each round's server and party coefficients (a difference of two
+  losses over mu) as the state after the last round shows them, its
+  change from the start along the round's direction over -lr. Absolute,
+  since a coefficient near 0 is as noisy as a large one; each gap is
+  the rounding of the losses. A later round's loss is left out here: it
+  follows the earlier rounds' updates, whose coefficients already differ
+  by that rounding (``loss_gap`` holds it, against a wider limit).
 - ``dir_gap``: 1 - |cos(d_prog, d_ref)| of the f32 party block's change,
   its leaves taken as one vector: the first round's activated party for
   the vfl-zoo step, every leaf after the whole call for the scan. 0
@@ -135,6 +144,33 @@ def median_gaps(stats: dict) -> tuple[float, float]:
     norm = [abs(np_ - nr) / max(nr, med) for np_, nr, _ in keep.values()]
     cos = [1.0 - abs(c) for _, _, c in keep.values()]
     return float(np.median(norm)), float(np.median(cos))
+
+
+def named(prefix: str, tree) -> dict:
+    """{prefix + leaf path: leaf} over ``tree``."""
+    from chipbench.weights import path_name
+    return {prefix + path_name(path): a
+            for path, a in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@jax.jit
+def _along(p, s, u):
+    d = p.astype(jnp.float32) - s.astype(jnp.float32)
+    return jnp.stack([jnp.sum(d * u), jnp.sum(u * u)])
+
+
+def along(last: dict, start: dict, dirs: dict) -> float:
+    """<d, u> / <u, u> of the change d from ``start`` to ``last`` along
+    ``dirs`` ({name: f32 direction leaf}), its leaves taken together as
+    one vector."""
+    num = den = 0.0
+    for name, u in dirs.items():
+        p = _pick(last[name])
+        if not isinstance(p, jax.Array):
+            p = jax.device_put(np.asarray(p), next(iter(u.devices())))
+        a, b = np.asarray(_along(p, _pick(start[name]), u), np.float64)
+        num, den = num + float(a), den + float(b)
+    return num / den
 
 
 def loss_gap(h_prog, h_ref) -> float:

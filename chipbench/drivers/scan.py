@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import check as chk
-from chipbench import flops
 from chipbench.common import load_module, seed_key
 from chipbench.traffic_gen import classification_table
 from chipbench.weights import fill
@@ -36,6 +35,7 @@ class Cell:
         self.n_check = traffic["check_rounds"]
         self.q = self.v["num_parties"]
         self.precision = cfg["matmul_precision"]
+        self.family = load_module("families", cfg["family"])
 
     def _start(self):
         key = seed_key(self.seed)
@@ -51,9 +51,8 @@ class Cell:
         return self._make_keys(jax.random.fold_in(seed_key(self.seed), 4), j)
 
     def setup(self):
-        from repro.configs import PaperLRConfig, VFLConfig
+        from repro.configs import VFLConfig
         from repro.core import asyrevel
-        from repro.core.vfl import PaperLRModel
         from repro.launch.mesh import make_data_mesh
 
         t0 = time.perf_counter()
@@ -66,8 +65,7 @@ class Cell:
                              max_delay=v["max_delay"],
                              direction=v["direction"], codec=v["codec"],
                              lam=v["lam"], fused=v["fused"])
-        model = PaperLRModel(PaperLRConfig(num_features=d,
-                                           num_parties=self.q))
+        model = self.family.program_model(self.cfg)
         mesh = make_data_mesh(self.traffic["mesh"])
         with jax.default_matmul_precision(self.precision):
             self.fn = asyrevel.make_sharded_train_fn(
@@ -121,7 +119,8 @@ class Cell:
                 "failed": failed}
 
     def flops_per_round(self) -> float:
-        return flops.lr_round(self.data_cfg["features"], self.q, self.B)
+        return self.family.round_flops(self.data_cfg, self.cfg["vfl"],
+                                       self.B)
 
     def release(self):
         self.state = None

@@ -22,25 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import check as chk
-from chipbench import flops
 from chipbench.common import load_module, seed_key
 from chipbench.traffic_gen import lm_table
 from chipbench.weights import fill
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for the published config in ``cfg``."""
-    from repro.configs import get_config
-    m = cfg["model"]
-    base = get_config(cfg["arch"])
-    return base.replace(
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"],
-        head_dim=m["hidden_size"] // m["num_attention_heads"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        tie_embeddings=m["tie_word_embeddings"], rope_theta=m["rope_theta"],
-        norm_eps=m["rms_norm_eps"], dtype=m["torch_dtype"])
 
 
 def vfl_config(cfg: dict):
@@ -58,7 +42,9 @@ class Cell:
     def __init__(self, cfg: dict, traffic: dict, seed: int, devices):
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
         self.devices = devices
-        self.mcfg, self.vfl = model_config(cfg), vfl_config(cfg)
+        self.family = load_module("families", cfg["family"])
+        self.mcfg = self.family.program_config(cfg)
+        self.vfl = vfl_config(cfg)
         self.B, self.S = traffic["batch"], traffic["seq"]
         self.rows = traffic["table_rows"]
         self.n_check = traffic["check_rounds"]
@@ -72,7 +58,10 @@ class Cell:
         return (fill(self.shapes.w0, jax.random.fold_in(key, 1), self.std),
                 fill(self.shapes.parties, jax.random.fold_in(key, 2),
                      self.std),
-                jax.random.fold_in(key, 3))
+                self._state_key())
+
+    def _state_key(self):
+        return jax.random.fold_in(seed_key(self.seed), 3)
 
     def setup(self):
         from repro.core.asyrevel import AsyState
@@ -176,8 +165,8 @@ class Cell:
                             for i in slowest]}
 
     def flops_per_round(self) -> float:
-        return flops.zoo_round(self.cfg["model"], self.cfg["vfl"], self.B,
-                               self.S)
+        return self.family.round_flops(self.cfg["model"], self.cfg["vfl"],
+                                       self.B, self.S)
 
     def release(self):
         self.state = self.data = None
@@ -205,6 +194,27 @@ class Cell:
         return {"h": run["h"], "first": chk.named_leaves(w1, p1, q),
                 "last": chk.named_leaves(w3, p3, q)}
 
+    def coefficients(self, last: dict, start: dict, m: list) -> list:
+        """[(server, party)] for each round the reference follows: the
+        change from ``start`` to ``last`` along the round's server
+        direction and along its activated party's (``m``), over -lr.
+        The directions are the reference module's draws."""
+        ref = load_module("reference", self.cfg["reference"])
+        fresh = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
+            self.shapes.parties)
+        v, out = self.cfg["vfl"], []
+        for r, m_r in enumerate(m):
+            key = jax.random.fold_in(self._state_key(), r)
+            u0 = chk.named("w0/", ref.directions(ref.fold_name(key, "u0"),
+                                                 self.shapes.w0))
+            server = chk.along(last, start, u0) / -v["lr_server"]
+            del u0
+            u = chk.named(f"party{m_r}/",
+                          ref.directions(ref.fold_name(key, "u"), fresh))
+            out.append((server, chk.along(last, start, u) / -v["lr_party"]))
+        return out
+
     def readings(self, side: dict, ref_run: dict, start: dict) -> dict:
         ref = self.side_of(ref_run)
         m0 = ref_run["m"][0]
@@ -230,19 +240,26 @@ class Cell:
             return (ref_coeff * (np_ / nr) * np.sign(cos) if nr > 0
                     else float("nan"))
 
+        c_prog = self.coefficients(side["last"], start, ref_run["m"])
+        c_ref = self.coefficients(ref["last"], start, ref_run["m"])
+        value = max([abs(side["h"][0] - ref_run["h"][0])
+                     / self.cfg["vfl"]["mu"]]
+                    + [abs(a - b) for cp, cr in zip(c_prog, c_ref)
+                       for a, b in zip(cp, cr)])
         return {"loss_gap": chk.loss_gap(side["h"], ref_run["h"]),
-                "grad_gap": grad, "change_gap": change,
+                "value_gap": value, "grad_gap": grad, "change_gap": change,
                 "change_median": change_med, "dir_gap": direction,
                 "w0_sign_gap": server,
                 "_worst": {"grad": grad_leaf, "change": change_leaf,
                            "dir": [dir_leaf, dir_worst]},
                 "_loss0_gap": chk.loss_gap(side["h"][:1], ref_run["h"][:1]),
                 "_median": {"grad": grad_med, "dir": dir_med},
+                "_coeffs": {"program": c_prog, "reference": c_ref},
                 "_coeff_ref": ref_run["coeff"][0],
                 "_coeff_prog": float(coeff_of(f"party{m0}/embed",
                                               ref_run["coeff"][0])),
                 "_coeff0_ref": ref_run["coeff0"][0],
-                "_coeff0_prog": float(coeff_of("w0/embed",
+                "_coeff0_prog": float(coeff_of(self.family.SERVER_LEAF,
                                                ref_run["coeff0"][0]))}
 
 

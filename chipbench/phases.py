@@ -1,6 +1,5 @@
-"""Run one traced cell as ``run.py --trace 1`` does, and also attribute
-the traced window's device time to the program's named phases
-(``chipbench/scopes.py``).
+"""Run one traced cell as ``run.py --trace 1`` does, and print what the
+run read of the program's named phases (``chipbench/scopes.py``).
 
   python3 chipbench/phases.py --workload NAME --seed N \
       [--out FILE] [--record FILE]
@@ -11,7 +10,9 @@ stdout. Then stderr gets each phase's device self time per round (ms),
 event under each of the longest idle gaps. ``--out`` writes the same as
 JSON; ``--record`` keeps the first ``RECORD_MS`` milliseconds of the
 window as a small recorded trace with each op's phase, for
-``chipbench/tests/test_scopes.py``.
+``chipbench/tests/test_scopes.py``. The phases are those that run.py
+computes for its per-layer metrics (``scope_s``); this script reads
+them from the run and records nothing itself.
 """
 from __future__ import annotations
 
@@ -20,7 +21,10 @@ import json
 import sys
 from pathlib import Path
 
-sys.path[0] = str(Path(__file__).resolve().parent.parent)
+if __name__ == "__main__":
+    # the checkout, not this directory, leads the path; imported (by
+    # run.py) the path is left as it is, the program's src with it
+    sys.path[0] = str(Path(__file__).resolve().parent.parent)
 
 from chipbench import scopes  # noqa: E402
 from chipbench import trace_reduce as tr  # noqa: E402
@@ -63,6 +67,7 @@ def record_programs(programs: dict) -> None:
     """From now on, enter the HLO text of every program the process
     compiles or loads from the compile cache into ``programs``
     (``scopes.add_program``): what ``scopes.load`` looks op names up in.
+    ``run.py`` calls it before a traced run's set-up.
     Call after ``common.use_checkout_cache``, which must precede the
     import of jax."""
     from jax._src import compiler
@@ -84,30 +89,17 @@ def main(argv=None) -> int:
     p.add_argument("--out")
     p.add_argument("--record")
     args = p.parse_args(argv)
-    from chipbench import common, run
+    from chipbench import run
 
-    common.use_checkout_cache()
-    programs = {}
-    record_programs(programs)
-    got, emit, base = {}, common.emit, tr.load
-
-    def load(path):
-        rec = scopes.load(path, base(path), programs)
-        got["summary"] = scopes.reduce(rec)
-        if args.record:
-            Path(args.record).parent.mkdir(parents=True, exist_ok=True)
-            Path(args.record).write_text(json.dumps(trim(rec, RECORD_MS)))
-        return rec
-
-    def keep_result(result, compared):
-        got["result"] = result
-        emit(result, compared)
-
-    tr.load, common.emit = load, keep_result
+    got = {}
     rc = run.main(["--workload", args.workload, "--seed", args.seed,
-                   "--seconds", "10", "--trace", "1"])
+                   "--seconds", "10", "--trace", "1"], seen=got)
     if rc or "summary" not in got:
         return rc or 1
+    if args.record:
+        Path(args.record).parent.mkdir(parents=True, exist_ok=True)
+        Path(args.record).write_text(json.dumps(trim(got["record"],
+                                                     RECORD_MS)))
     out = per_round(got["summary"], got["result"]["attempted"])
     for k, v in out["phases_ms"].items():
         print(f"chipbench: phase {k} {v:.6f} ms a round", file=sys.stderr)

@@ -8,7 +8,8 @@ Meant for a copy of the benchmark whose configuration and traffic files
 were cut to a tiny size (chipbench/tests does this); nothing it prints
 is a device number. With --trace 1 the XLA CPU threads of the host plane
 stand in for a device plane, so that the reduction has something to
-read.
+read; each of their ops names its module and instruction, through which
+its op_name is found in the recorded programs.
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ import time
 from pathlib import Path
 
 T_START = time.perf_counter()
+CPU = "/device:CPU:0"
 
 
 def main(argv=None, before_run=None) -> int:
@@ -34,11 +36,11 @@ def main(argv=None, before_run=None) -> int:
     sys.path[0] = str(Path(__file__).resolve().parent.parent)
     import jax
 
-    from chipbench import run, trace_reduce
+    from chipbench import run, scopes, trace_reduce
 
     def load_cpu(path):
         pd = jax.profiler.ProfileData.from_file(path)
-        ops, spans = [], []
+        ops, spans, hlo = [], [], []
         for plane in pd.planes:
             for ln in plane.lines:
                 for ev in ln.events:
@@ -48,10 +50,18 @@ def main(argv=None, before_run=None) -> int:
                     elif ln.name.startswith("tf_XLA"):
                         ops.append((ev.name, float(ev.start_ns),
                                     float(ev.duration_ns)))
-        return {"devices": {"/device:CPU:0": ops}, "spans": spans,
-                "lines": {}}
+                        stats = dict(ev.stats)
+                        hlo.append((stats.get("hlo_module"),
+                                    stats.get("hlo_op")))
+        return {"devices": {CPU: ops}, "spans": spans, "lines": {},
+                "hlo": hlo}
 
-    trace_reduce.load = load_cpu
+    def op_names_cpu(path, rec, programs):
+        return dict(rec, host=[], op_names={CPU: [
+            programs.get(module, {}).get(op, "")
+            for module, op in rec["hlo"]]})
+
+    trace_reduce.load, scopes.load = load_cpu, op_names_cpu
     if before_run is not None:
         before_run()
     return run.main(argv, devices=jax.devices()[:chips], t_start=T_START)

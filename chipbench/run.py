@@ -4,10 +4,12 @@
 
 The cell, its configuration file and its traffic file are found by name
 through BENCHMARK.json; the configuration names its driver
-(chipbench/drivers/) and its reference (chipbench/reference/); each
-metric is read by chipbench/metrics/<name>.py. With --trace 0 the run
-prints the cell's end-to-end metrics, with --trace 1 its per-layer
-metrics from a profiler trace of a shorter window.
+(chipbench/drivers/), its model family (chipbench/families/) and its
+reference (chipbench/reference/); each metric is read by
+chipbench/metrics/<name>.py. With --trace 0 the run prints the cell's
+end-to-end metrics, with --trace 1 its per-layer metrics from a profiler
+trace of a shorter window, whose device time is also split by the
+program's named scopes (chipbench/scopes.py).
 
 The run exits non-zero, and prints no result, where JAX finds no TPU,
 fewer chips than the cell asks for, or a device kind that
@@ -49,9 +51,11 @@ def reported(metrics: list, workload: str) -> list:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def main(argv=None, devices=None, t_start=None) -> int:
+def main(argv=None, devices=None, t_start=None, seen=None) -> int:
     """``devices`` skips the look for a chip (the benchmark's own CPU
-    tests pass host devices); a run from the command line never does."""
+    tests pass host devices); a run from the command line never does.
+    A ``seen`` dict receives the traced run's loaded trace (``record``),
+    its reduction (``summary``) and the result (``result``)."""
     args = parse(argv)
     common.use_checkout_cache()
     spec = common.cell_spec(args.workload)
@@ -70,6 +74,11 @@ def main(argv=None, devices=None, t_start=None) -> int:
     else:
         peak = {"bf16_flops": 197e12}
     meter = common.CompileMeter()
+    programs = {}
+    if args.trace:
+        # every program's op_names, looked up when the trace is read
+        from chipbench import phases, scopes
+        phases.record_programs(programs)
     driver = common.load_module("drivers", cfg["driver"])
     cell = driver.Cell(cfg, traffic, args.seed, devices)
     t_devices = time.perf_counter() - (t_start or T_START)
@@ -93,8 +102,11 @@ def main(argv=None, devices=None, t_start=None) -> int:
         seconds = min(args.seconds, traffic["trace_seconds"])
         win, path = tr.capture(lambda: cell.window(seconds, annotate=True),
                                str(logdir))
-        summary = tr.reduce(tr.load(path))
+        record = scopes.load(path, tr.load(path), programs)
+        summary = scopes.reduce(record)
         shutil.rmtree(logdir, ignore_errors=True)
+        if seen is not None:
+            seen.update(record=record, summary=summary)
     else:
         win = cell.window(args.seconds)
     in_window = meter.compiles - compiles
@@ -133,6 +145,8 @@ def main(argv=None, devices=None, t_start=None) -> int:
     if summary is not None:
         result["breakdown"] = {"device_ops": summary["device_ops"],
                                "idle_gaps": summary["idle_gaps"]}
+    if seen is not None:
+        seen["result"] = result
     common.emit(result, compared)
     return 0
 

@@ -25,6 +25,13 @@ unchanged and adds:
   ``unscoped``. Self time is ``trace_reduce``'s rule, so the phases and
   ``unscoped`` add up to the ops' self time. Empty where no op names a
   phase: a program without the scopes, or no programs recorded.
+- within_s: for every identifier in any op's op_name path (a scope such
+  as ``server_forward``, or a name XLA or JAX put there), the self time
+  of the ops whose path holds it, each op counted once under each name
+  its path holds, mean over chips. A scope nested in another counts in
+  both, and a ``while`` op's own time counts under its path's names, so
+  a phase's ``within_s`` is at least its ``scope_s``. A scope that a
+  later program adds is read here with no change to ``SCOPES``.
 - gap_events: the longest idle gaps of the first chip, as
   ``idle_gaps`` has them, each with the innermost host event that covers
   its middle (``-`` where none does): JAX's or the TPU runtime's work,
@@ -124,25 +131,42 @@ def _window(rec):
 def reduce(rec: dict, top: int = 10) -> dict:
     out = tr.reduce(rec, top)
     w0, w1 = _window(rec)
-    by_scope, scoped = {}, False
+    by_scope, within, scoped = {}, {}, False
+    paths = {}     # op_name -> (its phase, the identifiers in its path)
     for plane, events in rec["devices"].items():
         names = rec.get("op_names", {}).get(plane, [""] * len(events))
-        inside = [((name, scope_of(op)), max(s, w0), min(s + d, w1))
+        inside = [((name, op), max(s, w0), min(s + d, w1))
                   for (name, s, d), op in zip(events, names)
                   if s < w1 and s + d > w0]
-        for (name, scope), _, _, own in tr._self_times(inside):
+        for (name, op), _, _, own in tr._self_times(inside):
+            if op not in paths:
+                paths[op] = (scope_of(op), set(_WORD.findall(op or "")))
+            scope, words = paths[op]
             scoped |= scope != UNSCOPED
             if WHILE.search(name):
                 scope = UNSCOPED
             by_scope[scope] = by_scope.get(scope, 0.0) + own
+            for w in words:
+                within[w] = within.get(w, 0.0) + own
     n = out["chips"]
     out["scope_s"] = ({k: v / n / 1e9 for k, v in by_scope.items()}
                       if scoped else {})
+    out["within_s"] = {k: v / n / 1e9 for k, v in within.items()}
     out["gap_events"] = [
         [tr._label(rec["spans"], (a + b) / 2), (b - a) / 1e9,
          _innermost(rec.get("host", []), (a + b) / 2)]
-        for a, b in _gaps(rec, w0, w1)][:top]
+        for a, b in _gaps(rec, w0, w1)[:top]]
     return out
+
+
+def phase_ms(rec: dict, phase: str):
+    """Device self time a round of the ops in ``phase`` (ms), from the
+    run record's ``scope_s``: 0 for a phase with no op, None where the
+    trace names no phase at all."""
+    t = rec.get("trace")
+    if not t or not t.get("scope_s") or not rec.get("rounds"):
+        return None
+    return 1e3 * t["scope_s"].get(phase, 0.0) / rec["rounds"]
 
 
 def _gaps(rec, w0, w1):

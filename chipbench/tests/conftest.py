@@ -17,24 +17,12 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-# the published widths cut to a few hundred thousand parameters
-TINY_MODEL = dict(hidden_size=64, intermediate_size=128,
-                  num_hidden_layers=2, num_attention_heads=4,
-                  num_key_value_heads=4, vocab_size=512)
+from chipbench.common import load_module  # noqa: E402
+
+# the tiny sizes of a configuration and the limits of its check there
+# come from its family module (chipbench/families/); those of traffic
+# files, here
 TINY_LM = dict(seq=16, table_rows=64, trace_seconds=0.5)
-TINY_LR = dict(rows=4000, features=80, block_rows=1000)
-# limits at the tiny sizes, set as the chip's are (PERF.md), from the
-# host CPU's readings on seeds 1-12: program's largest below, control's
-# and each fault's smallest above
-TINY_CHECK = {
-    # loss_gap: program <= 7.9e-5, control >= 1.7e-4, half batch
-    # >= 1.3e-3; dir_gap: program about 1e-7, unchanged 1; w0_sign_gap:
-    # program, control and half batch 0, unchanged and no server update 1
-    "zoo_step": {"loss_gap": 1.2e-4, "dir_gap": 1e-3, "w0_sign_gap": 0.0},
-    # program 0, 1.3e-4 and 0; the bf16 control >= 5.8e-5, 0.061 and
-    # 7.5e-4; unchanged 1 for both of the last two
-    "scan": {"loss_gap": 1e-5, "change_gap": 0.01, "dir_gap": 1e-4},
-}
 
 
 # cells whose traffic files are kept for a later PR that adds them as
@@ -63,27 +51,27 @@ def driver_of(cell: dict) -> str:
 
 def shrink(root: Path) -> None:
     """Cut every configuration and traffic file under ``root`` to tiny
-    sizes, in place, and add the deferred cells to its BENCHMARK.json."""
+    sizes, in place, and add the deferred cells to its BENCHMARK.json.
+    A configuration takes its family's ``TINY`` sizes into its ``model``
+    block (its ``data`` block where it has no model) and its family's
+    ``TINY_LIMITS`` as the limits of each of its cells."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     bench["workloads"] += DEFERRED
     for m in bench["end_to_end"] + bench["per_layer"]:
         if m["name"] == "round_p95_ms":
             m["workloads"] += [w["name"] for w in DEFERRED]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    drivers = {}
+    limits = {}
     for c in bench["configs"]:
         p = root / c["file"]
         cfg = json.loads(p.read_text())
-        drivers[c["name"]] = cfg["driver"]
-        if "model" in cfg:
-            cfg["model"].update(TINY_MODEL)
-        if "data" in cfg:
-            cfg["data"].update(TINY_LR)
+        fam = load_module("families", cfg["family"])
+        cfg["model" if "model" in cfg else "data"].update(fam.TINY)
+        limits[c["name"]] = fam.TINY_LIMITS
         p.write_text(json.dumps(cfg))
     for w in bench["workloads"]:
         p = root / "chipbench" / "limits" / f"{w['name']}.json"
-        p.write_text(json.dumps(
-            {"limits": TINY_CHECK[drivers[w["config"]]]}))
+        p.write_text(json.dumps({"limits": limits[w["config"]]}))
     for p in (root / "chipbench" / "traffic").glob("*.json"):
         t = json.loads(p.read_text())
         if "seq" in t:
@@ -124,7 +112,9 @@ def run_cell(root: Path, workload: str, seed: int = 5, seconds: float = 1.0,
     cmd += ["--workload", workload, "--seed", str(seed), "--seconds",
             str(seconds), "--trace", str(trace)]
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1")
+    # as on the chip: the harness finds the program's src itself
     env.pop("XLA_FLAGS", None)
+    env.pop("PYTHONPATH", None)
     p = subprocess.run(cmd, cwd=root, env=env, capture_output=True,
                        text=True, timeout=timeout)
     assert p.returncode == 0, p.stderr[-4000:]

@@ -33,3 +33,19 @@ def test_sign_gap(coeff_prog, coeff_ref, fresh_dir, want):
 def test_loss_gap():
     assert chk.loss_gap([10.0, 10.001, 9.998], [10.0] * 3) == pytest.approx(
         2e-4)
+
+
+@pytest.mark.parametrize("coeff", [2.5, -0.3, 0.0])
+def test_along_reads_the_coefficient(coeff):
+    """A change of -lr * coeff * u, stored in bf16, read along u over -lr
+    gives coeff back, a state left on the host as one on the device."""
+    rng = np.random.default_rng(1)
+    start = {"w0/a": jnp.asarray(rng.normal(0, 0.02, 100_000), jnp.bfloat16),
+             "party0/b": (0, jnp.asarray(rng.normal(0, 0.02, (2, 3000)),
+                                         jnp.float32))}
+    u = {"w0/a": jnp.asarray(rng.normal(size=100_000), jnp.float32),
+         "party0/b": jnp.asarray(rng.normal(size=3000), jnp.float32)}
+    last = {"w0/a": np.asarray(_update(start["w0/a"], u["w0/a"], coeff)),
+            "party0/b": start["party0/b"][1][0] - 0.05 * coeff * u["party0/b"]}
+    got = chk.along(last, start, u) / -0.05
+    assert got == pytest.approx(coeff, abs=0.02)

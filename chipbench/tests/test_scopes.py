@@ -38,6 +38,25 @@ def test_each_op_counts_under_its_innermost_phase():
                                  scopes.UNSCOPED: 0.010})
 
 
+def test_within_counts_each_op_under_every_name_in_its_path():
+    rec = _rec([("fusion.1", 0, 10, "jit(step)/zo_perturb/add"),
+                ("fusion.2", 10, 5,
+                 "jit(step)/zo_update/jvp(server_forward)/dot_general"),
+                ("fusion.3", 20, 5, "jit(step)/zo_update/sub"),
+                ("copy.1", 30, 8, "jit(step)/copy"),
+                ("fusion.4", 40, 2, "")])
+    out = scopes.reduce(rec)
+    within = out["within_s"]
+    assert within["zo_update"] == pytest.approx(0.010)
+    assert within["server_forward"] == pytest.approx(0.005)
+    assert within["zo_perturb"] == pytest.approx(0.010)
+    # every op with an op_name holds jit and step: each counted once
+    assert within["jit"] == within["step"] == pytest.approx(0.028)
+    for phase, t in out["scope_s"].items():
+        if phase != scopes.UNSCOPED:
+            assert within[phase] >= t - 1e-15
+
+
 def test_a_while_loop_keeps_only_its_residual_unscoped():
     # the loop [0, 50] is inside a phase; its body ran two scoped ops
     loop = "%while.13 = (s32[], f32[8,250]) while((s32[], f32[8,250]) %t)"
@@ -87,6 +106,7 @@ def test_records_without_op_names_reduce_as_before(name):
     rec = json.loads((DATA / name).read_text())
     out = scopes.reduce(rec)
     assert out.pop("scope_s") == {}
+    assert out.pop("within_s") == {}
     gaps = out.pop("gap_events")
     assert out == tr.reduce(rec)
     assert [g[:2] for g in gaps] == out["idle_gaps"]
@@ -104,6 +124,29 @@ def test_recorded_scoped_trace_phases_add_up_to_busy():
     again = scopes.reduce(phases.trim(rec, 1e9))
     assert again["scope_s"] == pytest.approx(out["scope_s"])
     assert again["idle_gaps"] == out["idle_gaps"]
+
+
+def test_recorded_scoped_trace_within_matches_phases():
+    # the record keeps each op's phase alone, so no phase nests in
+    # another there: a phase's within_s is its scope_s, plus the own
+    # time of the while ops in it, which scope_s counts as unscoped
+    rec = json.loads((DATA / SCOPED).read_text())
+    out = scopes.reduce(rec)
+    w0, w1 = scopes._window(rec)
+    loops = {}
+    for plane, events in rec["devices"].items():
+        inside = [((n, op), max(s, w0), min(s + d, w1)) for (n, s, d), op
+                  in zip(events, rec["op_names"][plane])
+                  if s < w1 and s + d > w0]
+        for (n, op), _, _, own in tr._self_times(inside):
+            if scopes.WHILE.search(n):
+                loops[op] = loops.get(op, 0.0) + own / 1e9
+    for phase in scopes.SCOPES:
+        got = out["within_s"].get(phase, 0.0)
+        assert got >= out["scope_s"].get(phase, 0.0) - 1e-12
+        assert got == pytest.approx(out["scope_s"].get(phase, 0.0)
+                                    + loops.get(phase, 0.0), abs=1e-12)
+    assert set(out["within_s"]) >= set(out["scope_s"]) - {scopes.UNSCOPED}
 
 
 def test_op_names_follow_the_program_that_ran_them():
