@@ -19,6 +19,51 @@ class MoEConfig:
     d_ff_expert: int
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01  # load-balance auxiliary loss
+    # "capacity": the (E, C, d) dispatch that drops tokens over capacity,
+    # renormalizes the top-k gates and adds the Switch balance loss; its
+    # buffer shards over the mesh's 'model' axis (launch/dryrun.py's
+    # meshes). "dropless": a held share of the routed experts, every
+    # routed (token, slot) pair computed, the router in f32 and the top-k
+    # probabilities as they are (DeepSeek's MoEGate)
+    dispatch: str = "capacity"
+    num_shared_experts: int = 0   # dense SwiGLU of width n * d_ff_expert
+    experts_held: int = 0         # this chip's share: experts
+    first_expert: int = 0         # [first, first + held); 0 held = all
+
+    def __post_init__(self):
+        if self.dispatch not in ("capacity", "dropless"):
+            raise ValueError(f"unknown MoE dispatch {self.dispatch!r}")
+        if (self.experts_held or self.first_expert) and \
+                self.dispatch != "dropless":
+            raise ValueError("only the dropless layer holds a share")
+        if self.dispatch == "dropless" and self.router_aux_coef:
+            raise ValueError("the dropless layer has no balance loss")
+        if self.first_expert + self.held > self.num_experts:
+            raise ValueError("the held share runs past the routed experts")
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2) with YaRN rope."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # YaRN (rope_scaling); factor 1 is plain rope
+    rope_factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -49,6 +94,8 @@ class ModelConfig:
     norm_eps: float = 1e-5
     sliding_window: Optional[int] = None   # None = full attention
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None   # latent attention in every layer
+    first_k_dense: int = 0        # leading dense layers before the MoE stack
     ssm: Optional[SSMConfig] = None
     # --- enc-dec (whisper) ---
     enc_dec: bool = False
@@ -98,7 +145,13 @@ class ModelConfig:
         if self.moe is not None:
             moe = dataclasses.replace(self.moe, num_experts=4,
                                       top_k=min(self.moe.top_k, 2),
-                                      d_ff_expert=128)
+                                      d_ff_expert=128, experts_held=0,
+                                      first_expert=0)
+        mla = None
+        if self.mla is not None:
+            mla = dataclasses.replace(self.mla, kv_lora_rank=32,
+                                      qk_nope_head_dim=32,
+                                      qk_rope_head_dim=16, v_head_dim=32)
         ssm = None
         if self.ssm is not None:
             ssm = dataclasses.replace(self.ssm, chunk_size=16,
@@ -107,7 +160,8 @@ class ModelConfig:
             self, num_layers=2, d_model=d_model, num_heads=n_heads,
             num_kv_heads=kv, head_dim=64 if n_heads else 0,
             d_ff=min(self.d_ff, 512), vocab_size=min(self.vocab_size, 512),
-            moe=moe, ssm=ssm, num_encoder_layers=min(self.num_encoder_layers, 2),
+            moe=moe, mla=mla, first_k_dense=min(self.first_k_dense, 1),
+            ssm=ssm, num_encoder_layers=min(self.num_encoder_layers, 2),
             encoder_frames=min(self.encoder_frames, 32),
             sliding_window=(min(self.sliding_window, 64)
                             if self.sliding_window else None),
@@ -121,7 +175,14 @@ class ModelConfig:
         if not self.tie_embeddings:
             p += d * self.vocab_size                 # lm head
         per_layer = 0
-        if self.family != "ssm" and H:
+        if self.mla is not None:
+            a = self.mla
+            per_layer += (d * H * a.qk_head_dim
+                          + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                          + a.kv_lora_rank * H * (a.qk_nope_head_dim
+                                                  + a.v_head_dim)
+                          + H * a.v_head_dim * d)
+        elif self.family != "ssm" and H:
             per_layer += d * H * hd + 2 * d * KV * hd + H * hd * d
             if self.qkv_bias:
                 per_layer += (H + 2 * KV) * hd
@@ -132,12 +193,16 @@ class ModelConfig:
         elif self.family == "hybrid":
             di = self.ssm.expand * d
             per_layer += 2 * d * di + di * d + di * self.ssm.state_size * 2
+        attn_layer = per_layer
         if self.moe is not None:
-            per_layer += d * self.moe.num_experts     # router
-            per_layer += self.moe.num_experts * 3 * d * self.moe.d_ff_expert
+            m = self.moe
+            per_layer += d * m.num_experts            # router
+            per_layer += (m.held + m.num_shared_experts) * 3 * d \
+                * m.d_ff_expert
         elif self.family != "ssm":
             per_layer += 3 * d * self.d_ff            # swiglu
-        p += L * per_layer
+        dense = self.first_k_dense
+        p += (L - dense) * per_layer + dense * (attn_layer + 3 * d * self.d_ff)
         if self.enc_dec:
             enc_per = d * H * hd * 2 + 2 * d * KV * hd * 0  # rough: same attn
             enc_per = 4 * d * d + 2 * d * self.d_ff
@@ -150,9 +215,10 @@ class ModelConfig:
         if self.moe is None:
             return self.num_params()
         total = self.num_params()
-        all_expert = (self.num_layers * self.moe.num_experts * 3
+        L = self.num_layers - self.first_k_dense
+        all_expert = (L * self.moe.held * 3
                       * self.d_model * self.moe.d_ff_expert)
-        active_expert = (self.num_layers * self.moe.top_k * 3
+        active_expert = (L * self.moe.top_k * 3
                          * self.d_model * self.moe.d_ff_expert)
         return int(total - all_expert + active_expert)
 

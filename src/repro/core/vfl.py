@@ -283,7 +283,13 @@ class TransformerVFLModel(VFLModel):
         }
 
     def init_server(self, key):
-        return self.model.init(key)
+        """The server's backbone. Untied, it holds no input embedding: the
+        party towers give its inputs, so the ZO step would only draw,
+        perturb and update a table that nothing reads."""
+        w0 = self.model.init(key)
+        if not self.model.cfg.tie_embeddings:
+            del w0["embed"]
+        return w0
 
     def slice_features(self, x, m: int):
         return x        # tokens are shared ids; the SLICE is the embedding

@@ -1,5 +1,7 @@
 """Attention: GQA/MHA, causal / sliding-window / bidirectional / cross,
-optional QKV-bias and qk-norm, flash-style blocked softmax in pure JAX.
+optional QKV-bias and qk-norm, flash-style blocked softmax in pure JAX;
+and DeepSeek-V2's multi-head latent attention (MLA) with YaRN rope, whose
+decode cache holds the latent and the shared rope key.
 
 Memory discipline: the quadratic score matrix is never materialized for long
 sequences — training/prefill use an online-softmax scan over KV blocks
@@ -10,11 +12,13 @@ reference oracle.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MLAConfig, ModelConfig
 from repro.models.layers import apply_rope, dense_init, rms_norm
 
 NEG_INF = -1e30
@@ -23,6 +27,8 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------- params ---
 
 def attn_init(key, cfg: ModelConfig, dtype):
+    if cfg.mla is not None:
+        return mla_init(key, cfg, dtype)
     d, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, \
         cfg.resolved_head_dim
     ks = jax.random.split(key, 4)
@@ -65,14 +71,15 @@ def _project_qkv(p, cfg: ModelConfig, x, positions, rope: bool = True):
 # ------------------------------------------------------- blocked softmax ---
 
 def blocked_attention(q, k, v, *, causal: bool, kv_block: int = 512,
-                      q_positions=None, kv_positions=None):
+                      q_positions=None, kv_positions=None, scale=None):
     """Online-softmax attention scanning KV blocks; never builds (S,S).
 
-    q: (B,Sq,H,hd); k,v: (B,Skv,KV,hd) with H = KV*G.
-    Returns (B,Sq,H,hd).
+    q, k: (B,Sq|Skv,H|KV,hd); v: (B,Skv,KV,vd) with H = KV*G; scores are
+    scaled by ``scale`` (default hd**-0.5). Returns (B,Sq,H,vd).
     """
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape
+    vd = v.shape[-1]
     G = H // KV
     if q_positions is None:
         q_positions = jnp.arange(Sq)[None, :].repeat(B, 0)
@@ -85,7 +92,8 @@ def blocked_attention(q, k, v, *, causal: bool, kv_block: int = 512,
     # keep operands in model dtype; accumulate in f32 (MXU semantics) —
     # halves HBM/ICI bytes vs upcasting the operands (§Perf iteration A2)
     qg = q.reshape(B, Sq, KV, G, hd)
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
 
     def body(carry, blk):
         m, l, acc = carry
@@ -106,14 +114,14 @@ def blocked_attention(q, k, v, *, causal: bool, kv_block: int = 512,
         return (m_new, l, acc), None
 
     k_b = k.reshape(B, nblocks, kv_block, KV, hd).swapaxes(0, 1)
-    v_b = v.reshape(B, nblocks, kv_block, KV, hd).swapaxes(0, 1)
+    v_b = v.reshape(B, nblocks, kv_block, KV, vd).swapaxes(0, 1)
     pos_b = kv_positions.reshape(B, nblocks, kv_block).swapaxes(0, 1)
     m0 = jnp.full((B, Sq, KV, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Sq, KV, G), jnp.float32)
-    a0 = jnp.zeros((B, Sq, KV, G, hd), jnp.float32)
+    a0 = jnp.zeros((B, Sq, KV, G, vd), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (k_b, v_b, pos_b))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    return out.reshape(B, Sq, H, hd).astype(q.dtype)
+    return out.reshape(B, Sq, H, vd).astype(q.dtype)
 
 
 def windowed_attention(q, k, v, window: int, *, q_block: int = 512):
@@ -185,6 +193,9 @@ def decode_attention(q, k_cache, v_cache, cache_len):
 def attn_apply(p, cfg: ModelConfig, x, positions, *, causal=True,
                kv_block: int = 512):
     """Full-sequence self-attention (train / prefill)."""
+    if cfg.mla is not None:
+        return mla_apply(p, cfg, x, positions, causal=causal,
+                         kv_block=kv_block)
     q, k, v = _project_qkv(p, cfg, x, positions, rope=cfg.pos_emb == "rope")
     if cfg.sliding_window is not None and causal:
         o = windowed_attention(q, k, v, cfg.sliding_window)
@@ -239,6 +250,8 @@ def attn_decode_step(p, cfg: ModelConfig, x, cache, pos):
     With a sliding window the cache is a rolling buffer of size window and
     `pos` indexes modulo-window; RoPE uses absolute positions.
     """
+    if cfg.mla is not None:
+        return mla_decode_step(p, cfg, x, cache, pos)
     B = x.shape[0]
     pos = jnp.asarray(pos)
     pos_b = jnp.broadcast_to(pos, (B,))        # per-slot positions OK
@@ -274,6 +287,11 @@ def attn_decode_step(p, cfg: ModelConfig, x, cache, pos):
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
+    if cfg.mla is not None:
+        a = cfg.mla
+        return {"ckv": jnp.zeros((batch, max_len, a.kv_lora_rank), dtype),
+                "kpe": jnp.zeros((batch, max_len, a.qk_rope_head_dim),
+                                 dtype)}
     Smax = max_len if cfg.sliding_window is None \
         else min(max_len, cfg.sliding_window)
     KV, hd = cfg.num_kv_heads, cfg.resolved_head_dim
@@ -288,3 +306,148 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
         "k": jnp.zeros((batch, Smax, KV, hd), dtype),
         "v": jnp.zeros((batch, Smax, KV, hd), dtype),
     }
+
+
+# ------------------------------------------------- latent attention (MLA) ---
+# DeepSeek-V2 (modeling_deepseek.py: DeepseekV2Attention with no q-LoRA,
+# DeepseekV2YarnRotaryEmbedding). Per head the query is [q_nope | q_pe];
+# the keys and values come up from one RMS-normed latent c shared by all
+# heads, and the rope key k_pe is one head shared by all.
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(a: MLAConfig, theta: float) -> np.ndarray:
+    """YaRN's inverse frequencies of the rope dims: the extrapolated and
+    the interpolated (over ``rope_factor``) blended by the linear ramp
+    between the correction dims of ``beta_fast`` and ``beta_slow``."""
+    dim = a.qk_rope_head_dim
+    exps = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = 1.0 / theta ** exps
+    if a.rope_factor <= 1:
+        return extra.astype(np.float32)
+    inter = 1.0 / (a.rope_factor * theta ** exps)
+
+    def corr(rotations):
+        return (dim * math.log(a.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(a.beta_fast)), 0)
+    high = min(math.ceil(corr(a.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp                  # 1 where the frequency extrapolates
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def mla_softmax_scale(a: MLAConfig) -> float:
+    scale = a.qk_head_dim ** -0.5
+    if a.mscale_all_dim:
+        m = _yarn_mscale(a.rope_factor, a.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """x: (B,S,h,rope); DeepSeek de-interleaves the rope dims (even, then
+    odd) before rotating halves. cos and sin carry YaRN's mscale ratio."""
+    a = cfg.mla
+    inv = jnp.asarray(yarn_inv_freq(a, cfg.rope_theta))
+    m = (_yarn_mscale(a.rope_factor, a.mscale)
+         / _yarn_mscale(a.rope_factor, a.mscale_all_dim))
+    ang = positions.astype(jnp.float32)[..., None] * inv   # (B,S,rope/2)
+    cos = (jnp.cos(ang) * m)[..., None, :]
+    sin = (jnp.sin(ang) * m)[..., None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)
+
+
+def mla_init(key, cfg: ModelConfig, dtype):
+    d, H, a = cfg.d_model, cfg.num_heads, cfg.mla
+    r = a.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, H * a.qk_head_dim, dtype),
+        "wkv_a": dense_init(ks[1], d, r + a.qk_rope_head_dim, dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": dense_init(ks[2], r, H * (a.qk_nope_head_dim
+                                           + a.v_head_dim), dtype),
+        "wo": dense_init(ks[3], H * a.v_head_dim, d, dtype),
+    }
+
+
+def _mla_project(p, cfg: ModelConfig, x, positions):
+    """(q_nope (B,S,H,nope), q_pe (B,S,H,rope), c (B,S,r), k_pe
+    (B,S,1,rope)), the rope parts rotated."""
+    B, S, _ = x.shape
+    a, H = cfg.mla, cfg.num_heads
+    q = jnp.dot(x, p["wq"]).reshape(B, S, H, a.qk_head_dim)
+    ckv = jnp.dot(x, p["wkv_a"])
+    c = rms_norm(ckv[..., :a.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    k_pe = ckv[..., None, a.kv_lora_rank:]
+    return (q[..., :a.qk_nope_head_dim],
+            _mla_rope(q[..., a.qk_nope_head_dim:], positions, cfg), c,
+            _mla_rope(k_pe, positions, cfg))
+
+
+def mla_apply(p, cfg: ModelConfig, x, positions, *, causal=True,
+              kv_block: int = 512):
+    """Full sequence: k and v expanded from the latent, the shared rope
+    key broadcast to every head, one softmax over the 192-wide scores."""
+    B, S, _ = x.shape
+    a, H = cfg.mla, cfg.num_heads
+    with jax.named_scope("mla"):
+        q_nope, q_pe, c, k_pe = _mla_project(p, cfg, x, positions)
+        kv = jnp.dot(c, p["wkv_b"]).reshape(
+            B, S, H, a.qk_nope_head_dim + a.v_head_dim)
+        q = jnp.concatenate([q_nope, q_pe], -1)
+        k = jnp.concatenate([kv[..., :a.qk_nope_head_dim],
+                             jnp.broadcast_to(k_pe, q_pe.shape)], -1)
+        o = blocked_attention(q, k, kv[..., a.qk_nope_head_dim:],
+                              causal=causal, kv_block=kv_block,
+                              q_positions=positions, kv_positions=positions,
+                              scale=mla_softmax_scale(a))
+        out = jnp.dot(o.reshape(B, S, H * a.v_head_dim), p["wo"])
+    return out, (c, k_pe)
+
+
+def mla_decode_step(p, cfg: ModelConfig, x, cache, pos):
+    """One token against the latent cache {"ckv": (B,Smax,r), "kpe":
+    (B,Smax,rope)}: the up-projection is absorbed into the query (scores
+    against c) and into the output (values from c), so the cache holds
+    r + rope numbers a token."""
+    B = x.shape[0]
+    a, H = cfg.mla, cfg.num_heads
+    pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
+    with jax.named_scope("mla"):
+        q_nope, q_pe, c, k_pe = _mla_project(p, cfg, x, pos_b[:, None])
+        bidx = jnp.arange(B)
+        ckv = cache["ckv"].at[bidx, pos_b].set(
+            c[:, 0].astype(cache["ckv"].dtype))
+        kpe = cache["kpe"].at[bidx, pos_b].set(
+            k_pe[:, 0, 0].astype(cache["kpe"].dtype))
+        w = p["wkv_b"].reshape(a.kv_lora_rank, H,
+                               a.qk_nope_head_dim + a.v_head_dim)
+        f32 = jnp.float32
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0],
+                           w[..., :a.qk_nope_head_dim],
+                           preferred_element_type=f32)
+        s = (jnp.einsum("bhr,bsr->bhs", q_lat, ckv,
+                        preferred_element_type=f32)
+             + jnp.einsum("bhp,bsp->bhs", q_pe[:, 0], kpe,
+                          preferred_element_type=f32))
+        s = s * mla_softmax_scale(a)
+        valid = jnp.arange(ckv.shape[1])[None, :] <= pos_b[:, None]
+        s = jnp.where(valid[:, None, :], s, NEG_INF)
+        o_lat = jnp.einsum("bhs,bsr->bhr", jax.nn.softmax(s, axis=-1), ckv,
+                           preferred_element_type=f32)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, w[..., a.qk_nope_head_dim:],
+                       preferred_element_type=f32).astype(x.dtype)
+        out = jnp.dot(o.reshape(B, 1, H * a.v_head_dim), p["wo"])
+    return out, {"ckv": ckv, "kpe": kpe}

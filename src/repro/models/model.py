@@ -43,10 +43,14 @@ class Model:
         params = {
             "embed": embedding_init(ks[0], cfg.vocab_size, cfg.d_model,
                                     self.dtype),
-            "layers": tf.stacked_layers_init(ks[1], cfg, self.dtype,
-                                             cfg.num_layers),
+            "layers": tf.stacked_layers_init(
+                ks[1], cfg, self.dtype, cfg.num_layers - cfg.first_k_dense),
             "final_norm": jnp.ones((cfg.d_model,), self.dtype),
         }
+        if cfg.first_k_dense:
+            params["dense_layers"] = tf.stacked_layers_init(
+                jax.random.fold_in(ks[1], 1), tf.dense_config(cfg),
+                self.dtype, cfg.first_k_dense)
         if not cfg.tie_embeddings:
             params["lm_head"] = embedding_init(
                 ks[2], cfg.vocab_size, cfg.d_model, self.dtype).T
@@ -94,6 +98,14 @@ class Model:
                                 positions, causal=False)
         return rms_norm(x, params["encoder"]["final_norm"], cfg.norm_eps)
 
+    def _backbone(self, params, x, positions, enc_out=None):
+        """The leading dense layers, then the scanned stack."""
+        cfg = self.cfg
+        if cfg.first_k_dense:
+            x = tf.prefix_forward(params["dense_layers"], cfg, x, positions)
+        return tf.stack_forward(params["layers"], cfg, x, positions,
+                                enc_out=enc_out, causal=True)
+
     def _head(self, params, x):
         cfg = self.cfg
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -111,8 +123,7 @@ class Model:
         enc_out = None
         if cfg.enc_dec:
             enc_out = self._encode(params, batch["frames"])
-        x, aux = tf.stack_forward(params["layers"], cfg, x, positions,
-                                  enc_out=enc_out, causal=True)
+        x, aux = self._backbone(params, x, positions, enc_out)
         return self._head(params, x), aux
 
     def loss(self, params, batch):
@@ -126,8 +137,7 @@ class Model:
                                   jnp.arange(S)[None, :].repeat(B, 0))
             enc_out = (self._encode(params, batch["frames"])
                        if cfg.enc_dec else None)
-            x, aux = tf.stack_forward(params["layers"], cfg, x, positions,
-                                      enc_out=enc_out, causal=True)
+            x, aux = self._backbone(params, x, positions, enc_out)
             x = rms_norm(x, params["final_norm"], cfg.norm_eps)
             w = params["embed"].T if cfg.tie_embeddings \
                 else params["lm_head"]
@@ -143,8 +153,13 @@ class Model:
     # ----------------------------------------------------------- decode ---
     def init_cache(self, params, batch_size: int, max_len: int, frames=None):
         cfg = self.cfg
-        cache = {"layers": tf.stacked_cache_init(cfg, batch_size, max_len,
-                                                 self.dtype, cfg.num_layers)}
+        cache = {"layers": tf.stacked_cache_init(
+            cfg, batch_size, max_len, self.dtype,
+            cfg.num_layers - cfg.first_k_dense)}
+        if cfg.first_k_dense:
+            cache["dense_layers"] = tf.stacked_cache_init(
+                tf.dense_config(cfg), batch_size, max_len, self.dtype,
+                cfg.first_k_dense)
         if cfg.enc_dec:
             assert frames is not None, "enc-dec decode needs encoder frames"
             enc_out = self._encode(params, frames)
@@ -170,11 +185,14 @@ class Model:
             pe = jax.vmap(lambda q: sinusoidal_position_at(
                 q, cfg.d_model))(pos_b)
             x = x + pe[:, None, :].astype(self.dtype)
-        x, new_layer_caches = tf.stack_decode(
+        new_cache = dict(cache)
+        if cfg.first_k_dense:
+            x, new_cache["dense_layers"] = tf.stack_decode(
+                params["dense_layers"], tf.dense_config(cfg), x,
+                cache["dense_layers"], pos)
+        x, new_cache["layers"] = tf.stack_decode(
             params["layers"], cfg, x, cache["layers"], pos,
             cross_kv=cache.get("cross_kv"))
-        new_cache = dict(cache)
-        new_cache["layers"] = new_layer_caches
         return self._head(params, x), new_cache
 
 

@@ -7,6 +7,16 @@ shards cleanly over the mesh 'model' axis (expert parallelism) — and results
 are gathered back weighted by the router gates. Tokens beyond an expert's
 capacity C = ceil(N*top_k/E * capacity_factor) are dropped (standard
 Switch/GShard semantics); the router aux loss pushes the load toward balance.
+
+The dropless layer (``MoEConfig.dispatch == "dropless"``, DeepSeek-V2's
+DeepseekV2MoE) is one chip's share under expert parallelism: it holds
+experts [first, first + held) of the routed ones, routes every token over
+all of them in f32, and computes its held experts' part of the result for
+every (token, slot) pair routed to them, plus the shared experts. Pairs
+are sorted by expert and cut into row tiles of one expert each; a tile
+loop gathers each tile's tokens and runs the expert's SwiGLU on them, and
+each token then sums its slots' weighted rows, so the matmuls grow with
+the tokens routed here, not with a capacity.
 """
 from __future__ import annotations
 
@@ -15,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, swiglu_apply, swiglu_init
 from repro.sharding.ctx import constrain, current_mesh
 
 
@@ -37,21 +47,28 @@ def _cumsum_groups(n: int) -> int:
 def moe_init(key, cfg: ModelConfig, dtype):
     d = cfg.d_model
     m = cfg.moe
+    E = m.held                    # the router still scores all experts
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "router": dense_init(ks[0], d, m.num_experts, dtype, scale=0.02),
-        "w_gate": (jax.random.normal(ks[1], (m.num_experts, d, m.d_ff_expert),
+        "w_gate": (jax.random.normal(ks[1], (E, d, m.d_ff_expert),
                                      jnp.float32) / np.sqrt(d)).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (m.num_experts, d, m.d_ff_expert),
+        "w_up": (jax.random.normal(ks[2], (E, d, m.d_ff_expert),
                                    jnp.float32) / np.sqrt(d)).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (m.num_experts, m.d_ff_expert, d),
+        "w_down": (jax.random.normal(ks[3], (E, m.d_ff_expert, d),
                                      jnp.float32)
                    / np.sqrt(m.d_ff_expert)).astype(dtype),
     }
+    if m.num_shared_experts:
+        p["shared"] = swiglu_init(jax.random.fold_in(key, 4), d,
+                                  m.num_shared_experts * m.d_ff_expert, dtype)
+    return p
 
 
 def moe_apply(p, cfg: ModelConfig, x):
     """x: (B,S,d) -> (out (B,S,d), aux_loss scalar)."""
+    if cfg.moe.dispatch == "dropless":
+        return moe_dropless_apply(p, cfg, x)
     B, S, d = x.shape
     m = cfg.moe
     E, K = m.num_experts, m.top_k
@@ -110,3 +127,81 @@ def moe_apply(p, cfg: ModelConfig, x):
     out_flat = y[flat_idx, safe_pos] * gate_flat[:, None].astype(x.dtype)
     out = jnp.zeros((N, d), x.dtype).at[tok_ids].add(out_flat)
     return out.reshape(B, S, d), aux
+
+
+# ------------------------------------------------------------ dropless ---
+
+TILE = 256    # rows of one expert a grouped-matmul step computes
+
+
+def route(p, cfg: ModelConfig, xf):
+    """DeepSeek's MoEGate: f32 logits over every routed expert, softmax,
+    greedy top-k, the top-k probabilities not renormalized
+    (norm_topk_prob false, routed_scaling_factor 1). Returns (weights
+    (N,K) f32, experts (N,K) int)."""
+    m = cfg.moe
+    logits = jnp.dot(xf.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+
+
+def moe_dropless_apply(p, cfg: ModelConfig, x):
+    """x: (B,S,d) -> (shared + this share's routed part (B,S,d), 0): the
+    layer adds no balance loss (MoEConfig refuses a router_aux_coef)."""
+    B, S, d = x.shape
+    m = cfg.moe
+    E, K, N = m.held, m.top_k, B * S
+    xf = x.reshape(N, d)
+    with jax.named_scope("moe_dispatch"):
+        w, idx = route(p, cfg, xf)
+        local = idx.reshape(-1) - m.first_expert               # (N*K,)
+        held = (local >= 0) & (local < E)
+        key = jnp.where(held, local, E)     # pairs of absent experts: last
+        order = jnp.argsort(key, stable=True)                  # (N*K,)
+        counts = jnp.sum(jax.nn.one_hot(key, E + 1, dtype=jnp.int32),
+                         axis=0)[:E]
+        row0 = jnp.cumsum(counts) - counts     # first sorted row of each
+        tiles = (counts + TILE - 1) // TILE
+        tile_end = jnp.cumsum(tiles)
+        tile0 = tile_end - tiles                # first tile of each expert
+        n_tiles = tile_end[-1]
+        # the most tiles any routing can need: every pair held, each
+        # expert's last tile part full
+        max_tiles = -(-N * min(K, E) // TILE) + E
+        t_ids = jnp.arange(max_tiles)
+        t_expert = jnp.minimum(
+            jnp.searchsorted(tile_end, t_ids, side="right"), E - 1)
+        # each expert's rows start on a tile of their own: a pair's row
+        # in the tiles' output is its expert's first tile plus its rank
+        k_sorted = key[order]
+        e_sorted = jnp.minimum(k_sorted, E - 1)
+        at = tile0[e_sorted] * TILE + jnp.arange(N * K) - row0[e_sorted]
+        pos = jnp.zeros(N * K, jnp.int32).at[order].set(
+            jnp.where(k_sorted < E, at, 0)).reshape(N, K)
+        w = jnp.where(held.reshape(N, K), w, 0.0)
+
+    def tile(t):
+        e = t_expert[t]
+        with jax.named_scope("moe_dispatch"):
+            within = (t - tile0[e]) * TILE + jnp.arange(TILE)
+            rows = jnp.minimum(row0[e] + within, N * K - 1)
+            xt = xf[order[rows] // K]
+        with jax.named_scope("moe_experts"):
+            g = jnp.dot(xt, p["w_gate"][e])
+            u = jnp.dot(xt, p["w_up"][e])
+            return jnp.dot(jax.nn.silu(g) * u, p["w_down"][e])
+
+    def step(_, t):
+        # a scan over the static bound keeps the layer differentiable;
+        # tiles past this routing's count are skipped
+        return None, jax.lax.cond(
+            t < n_tiles, tile, lambda t: jnp.zeros((TILE, d), x.dtype), t)
+
+    _, ys = jax.lax.scan(step, None, t_ids)
+    with jax.named_scope("moe_dispatch"):
+        y = ys.reshape(max_tiles * TILE, d)[pos].astype(jnp.float32)
+        out = jnp.sum(y * w[..., None], axis=1).astype(x.dtype)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            out = out + swiglu_apply(p["shared"], xf)
+    return out.reshape(B, S, d), jnp.zeros((), jnp.float32)

@@ -3,7 +3,9 @@
 Every architecture family reduces to one homogeneous block type so the whole
 depth is a single ``lax.scan`` over stacked layer params (HLO size O(1) in
 depth; required for the 80-dry-run compile budget). Blocks are rematerialized
-(``jax.checkpoint``) during training when cfg.remat.
+(``jax.checkpoint``) during training when cfg.remat. A model whose first
+``first_k_dense`` layers are dense (DeepSeek-V2) runs those as single
+blocks ahead of the scanned stack (``prefix_forward``).
 """
 from __future__ import annotations
 
@@ -105,6 +107,26 @@ def stack_forward(stacked, cfg: ModelConfig, x, positions, enc_out=None,
     (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
                                stacked)
     return x, aux
+
+
+def dense_config(cfg: ModelConfig) -> ModelConfig:
+    """The leading dense layers' config: the model's attention, a SwiGLU
+    of width d_ff."""
+    return cfg.replace(family="dense", moe=None, first_k_dense=0)
+
+
+def prefix_forward(stacked, cfg: ModelConfig, x, positions, causal=True):
+    """The leading dense layers, one block each, outside the scan."""
+    dcfg = dense_config(cfg)
+
+    def one(p_l, x):
+        return block_forward(p_l, dcfg, x, positions, causal=causal)[0]
+
+    one = jax.checkpoint(one) if cfg.remat else one
+    for i in range(cfg.first_k_dense):
+        x = one(jax.tree.map(lambda a: a[i], stacked), x)
+        x = constrain(x, ("batch", None, None))
+    return x
 
 
 # -------------------------------------------------------------- decode -----

@@ -66,7 +66,8 @@ def test_smoke_decode_step(arch):
 
 @pytest.mark.parametrize("arch", ["deepseek-7b", "rwkv6-1.6b", "hymba-1.5b",
                                   "whisper-small", "chameleon-34b",
-                                  "qwen1.5-0.5b", "minicpm-2b", "yi-34b"])
+                                  "qwen1.5-0.5b", "minicpm-2b", "yi-34b",
+                                  "deepseek-v2-lite"])
 def test_prefill_decode_consistency(arch):
     """Decoding token-by-token must reproduce the full-sequence logits."""
     import dataclasses

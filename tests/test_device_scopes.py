@@ -1,7 +1,9 @@
 """The phases of an AsyREVEL round are named on the device.
 
 ``core/asyrevel.py``, ``core/exchange.py`` and the models' stale read
-(``core/vfl.py``) name Algorithm 1's steps with ``jax.named_scope``; the chip benchmark reads each device op's phase
+(``core/vfl.py``) name Algorithm 1's steps with ``jax.named_scope``, and
+the models name their DeepSeek-V2 layers (``models/attention.py``,
+``models/moe.py``); the chip benchmark reads each device op's phase
 from the op_name that XLA keeps in the compiled program
 (``chipbench/scopes.py``). Each name it reads must reach the compiled HLO
 of the two stepping paths it measures, the vfl-zoo step and the sharded
@@ -30,13 +32,13 @@ def _phases(compiled) -> set:
     return {scopes.scope_of(op) for op in ops}
 
 
-def _zoo_step(codec="bf16"):
+def _zoo_step(codec="bf16", arch="qwen1.5-0.5b"):
     from repro.configs import get_config
     from repro.launch import steps as step_lib
     from repro.models import build_model
     vfl = VFLConfig(num_parties=2, party_hidden=16, max_delay=2, mu=1e-3,
                     lr_party=1e-3, lr_server=1e-3, codec=codec)
-    model = build_model(get_config("qwen1.5-0.5b", reduced=True))
+    model = build_model(get_config(arch, reduced=True))
     _, init, step = step_lib.make_vfl_zoo_step(model, vfl)
     return init, step
 
@@ -60,6 +62,19 @@ def test_zoo_step_names_every_phase_but_the_gather():
              for k in ("tokens", "targets")}
     got = _phases(jax.jit(step).lower(state, batch).compile())
     assert set(scopes.SCOPES) - {"batch_gather"} <= got
+
+
+def test_zoo_step_names_the_deepseek_v2_layers():
+    """The benchmark's mla_ms and moe_*_ms metrics read these scopes
+    (chipbench/metrics/)."""
+    init, step = _zoo_step(arch="deepseek-v2-lite")
+    state = jax.eval_shape(init, jax.random.key(0))
+    batch = {k: jax.ShapeDtypeStruct((2, 8), jnp.int32)
+             for k in ("tokens", "targets")}
+    ops = scopes.op_names_of(jax.jit(step).lower(state, batch).compile()
+                             .as_text()).values()
+    names = {w for op in ops for w in scopes._WORD.findall(op)}
+    assert {"mla", "moe_dispatch", "moe_experts", "moe_shared"} <= names
 
 
 def test_lr_scan_names_every_phase_but_the_codec():
