@@ -199,8 +199,13 @@ def moe_dropless_apply(p, cfg: ModelConfig, x):
 
     _, ys = jax.lax.scan(step, None, t_ids)
     with jax.named_scope("moe_dispatch"):
-        y = ys.reshape(max_tiles * TILE, d)[pos].astype(jnp.float32)
-        out = jnp.sum(y * w[..., None], axis=1).astype(x.dtype)
+        # one slot's rows at a time, summed in f32 as they come: no
+        # (N, K, d) value, whose slot axis a TPU tile pads from 6 to 8
+        ys = ys.reshape(max_tiles * TILE, d)
+        out = ys[pos[:, 0]].astype(jnp.float32) * w[:, 0, None]
+        for k in range(1, K):
+            out = out + ys[pos[:, k]].astype(jnp.float32) * w[:, k, None]
+        out = out.astype(x.dtype)
     if "shared" in p:
         with jax.named_scope("moe_shared"):
             out = out + swiglu_apply(p["shared"], xf)

@@ -211,6 +211,44 @@ def test_skewed_router_drops_no_token(tokens):
     assert np.all(np.abs(np.asarray(routed)).max(-1) > 0)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its
+    equations (scan, cond and call bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_combine_sums_the_slots_without_an_f32_copy():
+    """The top-k combine at the share test's sizes, in bf16 as the cell
+    runs it: no f32 value holds every token's K slot rows (N·K·d
+    elements), and no matmul takes the (N, K) f32 gates at a precision
+    that would round them to bf16."""
+    cfg = get_config("deepseek-v2-lite", reduced=True).replace(d_model=32)
+    cfg = cfg.replace(moe=dataclasses.replace(
+        cfg.moe, num_experts=64, top_k=6, d_ff_expert=16, experts_held=8,
+        first_expert=8))
+    p = moe_mod.moe_init(jax.random.key(3), cfg, jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(4), (2, 40, 32), jnp.bfloat16)
+    N, K, d = 80, 6, 32
+    jaxpr = jax.make_jaxpr(
+        lambda p, x: moe_mod.moe_dropless_apply(p, cfg, x))(p, x).jaxpr
+    full = [str(v.aval) for e in _eqns(jaxpr) for v in e.outvars
+            if v.aval.dtype == jnp.float32 and v.aval.size == N * K * d]
+    assert not full, full
+    for e in _eqns(jaxpr):
+        if e.primitive.name != "dot_general":
+            continue
+        if any({N, K} <= set(v.aval.shape) for v in e.invars):
+            prec = e.params["precision"]
+            assert prec is not None and all(
+                q == jax.lax.Precision.HIGHEST for q in prec), e
+
+
 def test_untied_server_holds_no_input_embedding():
     vfl = VFLConfig(num_parties=4)
     ds = TransformerVFLModel(build_model(_tiny()), vfl)
