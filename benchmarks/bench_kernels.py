@@ -32,6 +32,7 @@ from repro.core.async_host import HostAsyncTrainer
 from repro.core.exchange import ZOExchange
 from repro.core.vfl import PaperLRModel, pad_features
 from repro.kernels import fused_round, ops, ref
+from repro.kernels.zo_update import zo_update_pallas
 
 
 def _time(f, *args, n=3):
@@ -54,7 +55,7 @@ _DP = DPConfig(noise_multiplier=1.3, clip=1.0)
 
 
 def _ex(codec, dp, fused, K=1):
-    # rademacher directions so the seed-replay fused ops are in play
+    # rademacher directions: the law the Pallas zo_update kernel draws
     return ZOExchange.from_config(VFLConfig(
         num_parties=4, mu=1e-3, codec=codec, num_directions=K,
         direction="rademacher", dp=dp, fused=fused))
@@ -91,17 +92,10 @@ def fused_op_rows():
     rows.append(("fused_encode_up_pallas_int8_dp", us_p,
                  "parity=bitwise;note=correctness timing"))
 
-    # perturb + apply_direction: the party-side fused ops
+    # apply_direction: the party-side fused op
     w = {"w": jax.random.normal(jax.random.fold_in(key, 1), (1 << 16,))}
     ex_u = _ex("f32", None, fused=False)
-    us_u = _time(lambda: ex_u.perturb(w, key), n=10)
-    us_f = _time(lambda: fused_round.perturb(w, key, ex_u.mu), n=10)
-    p_u, u_u = ex_u.perturb(w, key)
-    p_f, u_f = fused_round.perturb(w, key, ex_u.mu)
-    assert _wires_equal(p_u, p_f) and _wires_equal(u_u, u_f)
-    rows.append(("fused_perturb", us_f,
-                 f"unfused_us={us_u:.1f};speedup={us_u / us_f:.2f};"
-                 f"parity=bitwise"))
+    _, u_u = ex_u.perturb(w, key)
     coeff, lr = np.float32(0.37), 1e-2
     us_u = _time(lambda: ex_u.apply_direction(w, u_u, coeff, lr), n=10)
     us_f = _time(lambda: fused_round.apply_direction_fused(
@@ -230,7 +224,7 @@ def legacy_rows():
     w_ = jax.random.normal(jax.random.fold_in(key, 7), (1 << 16,))
     bits = jax.random.bits(jax.random.fold_in(key, 8), (1 << 16,),
                            jnp.uint32)
-    us = _time(lambda: ops.zo_update({"w": w_}, {"w": bits}, 0.01))
+    us = _time(lambda: zo_update_pallas(w_, bits, jnp.float32(0.01)))
     n = w_.size
     materialized = 3 * n * 4          # read w, read u(f32), write w
     seedreplay = 2 * n * 4            # read w, write w (bits on-chip PRNG)

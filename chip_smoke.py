@@ -340,7 +340,7 @@ def phase_kernels(interpret: bool = False, big: bool = True):
     coeff, lr = jnp.float32(0.37), 1e-2
     _bitwise("zo_apply pallas vs zoo.apply_zo_update",
              fused_round.zo_apply(wt, key, jnp.float32(lr * coeff),
-                                  impl="pallas", interpret=interpret),
+                                  interpret=interpret),
              zoo.apply_zo_update(wt, key, "rademacher", coeff, lr))
 
     c = _rand((4, 512), jnp.float32, 210)

@@ -332,8 +332,9 @@ class VFLConfig:
     party_layers: int = 2         # internal-only: depth of F_m (paper:
     #                               2-layer FCN; sized by --arch)
     direction: str = "gaussian"   # internal-only: gaussian (AsyREVEL-Gau) |
-    #                               uniform (-Uni) | rademacher (fused-kernel
-    #                               seed replay); library/bench knob
+    #                               uniform (-Uni) | rademacher (the law the
+    #                               Pallas zo_update kernel draws);
+    #                               library/bench knob
     mu: float = 1e-3              # smoothing parameter mu_m (--mu)
     lr_party: float = 1e-3        # flag: --lr — eta_m
     lr_server: float = 1e-3 / 8   # flag: --lr — eta_0 = eta / q (paper
@@ -343,8 +344,6 @@ class VFLConfig:
     #                               bound is RuntimeConfig.max_staleness
     activation_probs: Optional[Tuple[float, ...]] = None  # internal-only:
     #                               p_m (Assumption 3); bench schedule knob
-    seed_replay: bool = False     # internal-only: MeZO-style u regeneration
-    #                               (beyond-paper); implied by --fused
     num_directions: int = 1       # internal-only: directions averaged per
     #                               estimate (variance reduction,
     #                               beyond-paper; paper cites Liu et al.
@@ -358,9 +357,13 @@ class VFLConfig:
     dp: Optional[DPConfig] = None  # flag: --dp-epsilon — clip-then-noise
     #                               defense at the codec seam (src/repro/dp;
     #                               None = undefended)
-    fused: bool = False           # route releases through the fused
-    #                               kernels/fused_round fast path (bitwise
-    #                               equal to the unfused seam; --fused)
+    fused: bool = False           # route the releases (defend, encode_up,
+    #                               roundtrip_up), apply_direction and the
+    #                               host executor's release jits through
+    #                               the kernels/fused_round fast path
+    #                               (bitwise equal to the unfused seam;
+    #                               --fused); the ZO draw and its apply
+    #                               are core/zoo's either way
 
 
 @dataclass(frozen=True)

@@ -1,5 +1,5 @@
 """The paper's contribution: ZOO-VFL framework + AsyREVEL algorithms."""
 from repro.core.zoo import (perturb, zo_coefficient, zo_gradient,  # noqa
-                            direction_tree, zo_gradient_from_seed)
+                            direction_tree)
 from repro.core.vfl import (VFLModel, PaperLRModel, PaperFCNModel,  # noqa
                             TransformerVFLModel)

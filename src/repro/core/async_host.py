@@ -190,10 +190,7 @@ def _party_apply_jit(vfl, w_m, u, coeff):
 @functools.partial(jax.jit, static_argnames=("vfl",))
 def _party_apply_k_jit(vfl, w_m, us, coeffs):
     """K-direction averaged update: w_m - lr * mean_k coeff_k * u_k."""
-    K = vfl.num_directions
-    g = jax.tree.map(
-        lambda u: jnp.mean(
-            coeffs.reshape((K,) + (1,) * (u.ndim - 1)) * u, axis=0), us)
+    g = ZOExchange.mean_direction(coeffs, us)
     return jax.tree.map(
         lambda a, gg: (a - vfl.lr_party * gg).astype(a.dtype), w_m, g)
 

@@ -272,8 +272,8 @@ class ShardFoldedExchange(ZOExchange):
     def __init__(self, base: ZOExchange, axis_name: str):
         super().__init__(mu=base.mu, direction=base.direction,
                          lam=base.lam, num_directions=base.num_directions,
-                         seed_replay=base.seed_replay, codec=base.codec,
-                         meter=None, dp=base.dp, fused=base.fused)
+                         codec=base.codec, meter=None, dp=base.dp,
+                         fused=base.fused)
         self.axis_name = axis_name
 
     def _codec_key(self, key):

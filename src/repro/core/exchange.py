@@ -18,8 +18,7 @@ Mapping to Algorithm 1 (see also docs/exchange.md):
                                                         party_gradient()
   line 7  (party update w_m)                            apply_block(),
                                                         apply_direction(),
-                                                        apply_from_seed(),
-                                                        apply_fused()
+                                                        apply_from_seed()
   lines 9-11 (server's own estimate + update, Eq. 17)   server_update()
 
 Codec-aware transport: the up-link payload (the c function values — the
@@ -219,20 +218,21 @@ class ZOExchange:
     """
 
     def __init__(self, mu: float, direction: str = "gaussian",
-                 lam: float = 0.0, num_directions: int = 1,
-                 seed_replay: bool = False, codec="f32",
+                 lam: float = 0.0, num_directions: int = 1, codec="f32",
                  meter: CommsMeter | None = None, dp=None,
                  fused: bool = False):
         self.mu = mu
         self.direction = direction
         self.lam = lam
         self.num_directions = num_directions
-        self.seed_replay = seed_replay
         self.codec = get_codec(codec)
         self.meter = meter
-        # fused=True routes every release through the single-dispatch
+        # fused=True routes every release (defend, encode_up,
+        # roundtrip_up) and apply_direction through the single-dispatch
         # kernels/fused_round fast path; the unfused code below stays the
-        # bit-parity oracle (tests/test_kernels.py pins them equal).
+        # bit-parity oracle (tests/test_kernels.py pins them equal). The
+        # direction's draw and its seed-regenerated apply have one
+        # implementation, core/zoo, whatever ``fused`` says.
         self.fused = bool(fused)
         # a disabled DPConfig (eps=inf) normalizes to None so the
         # defended-off exchange IS the undefended one (same hash, same
@@ -250,7 +250,6 @@ class ZOExchange:
                     meter: CommsMeter | None = None) -> "ZOExchange":
         return cls(mu=vfl.mu, direction=vfl.direction, lam=vfl.lam,
                    num_directions=vfl.num_directions,
-                   seed_replay=vfl.seed_replay,
                    codec=getattr(vfl, "codec", "f32"), meter=meter,
                    dp=getattr(vfl, "dp", None),
                    fused=getattr(vfl, "fused", False))
@@ -327,8 +326,6 @@ class ZOExchange:
     @jax.named_scope("zo_perturb")
     def perturb(self, w, key):
         """w + mu * u. Returns (perturbed_tree, u_tree)."""
-        if self.fused and self.direction == "rademacher":
-            return fused_round.perturb(w, key, self.mu)
         return zoo.perturb(w, key, self.mu, self.direction)
 
     def coefficient(self, f_plus, f_base):
@@ -337,7 +334,7 @@ class ZOExchange:
         return zoo.zo_coefficient(f_plus, f_base, self.mu)
 
     def party_gradient(self, w_m, key, f_base, f_of):
-        """The party-side estimate: K-direction averaged or seed-replay.
+        """The party-side estimate, averaged over K directions.
 
         ``f_of(w_pert, k_dir)`` evaluates the full objective at the
         perturbed block — it hides one (c_hat up, h_bar down) round trip
@@ -354,16 +351,6 @@ class ZOExchange:
         exchanges fuse into a single multi-direction dispatch.
         """
         K = self.num_directions
-        if K == 1 and self.seed_replay:
-            # MeZO-style: keep only the scalar coefficient; regenerate u
-            # at the update site (fused-kernel path on TPU).
-            w_p, _ = self.perturb(w_m, key)
-            coeff = self.coefficient(f_of(w_p, key), f_base)
-            with jax.named_scope("zo_update"):
-                if self.fused and self.direction == "rademacher":
-                    return fused_round.zo_gradient_from_seed(w_m, key, coeff)
-                return zoo.zo_gradient_from_seed(key, w_m, self.direction,
-                                                 coeff)
         if K == 1:
             w_p, u = self.perturb(w_m, key)
             coeff = self.coefficient(f_of(w_p, key), f_base)
@@ -374,10 +361,18 @@ class ZOExchange:
         coeffs = jax.vmap(
             lambda f: self.coefficient(f, f_base))(jax.vmap(f_of)(w_ps, keys))
         with jax.named_scope("zo_update"):
-            return jax.tree.map(
-                lambda u: jnp.mean(
-                    coeffs.reshape((K,) + (1,) * (u.ndim - 1)) * u, axis=0),
-                us)
+            return self.mean_direction(coeffs, us)
+
+    @staticmethod
+    def mean_direction(coeffs, us):
+        """mean_k coeff_k * u_k: how K directions combine into one
+        estimate. ``coeffs`` is (K,); every leaf of ``us`` stacks the K
+        directions on its leading axis."""
+        K = coeffs.shape[0]
+        return jax.tree.map(
+            lambda u: jnp.mean(
+                coeffs.reshape((K,) + (1,) * (u.ndim - 1)) * u, axis=0),
+            us)
 
     # ---- update apply (Algorithm 1 line 7 / Eq. 15) ----------------------
     @jax.named_scope("zo_update")
@@ -399,25 +394,7 @@ class ZOExchange:
     @jax.named_scope("zo_update")
     def apply_from_seed(self, w, key, coeff, lr: float):
         """Seed-replay update: regenerate u from ``key``; never store it."""
-        if self.fused and self.direction == "rademacher":
-            return fused_round.zo_apply(
-                w, key, jnp.asarray(lr * coeff, jnp.float32))
         return zoo.apply_zo_update(w, key, self.direction, coeff, lr)
-
-    @jax.named_scope("zo_update")
-    def apply_fused(self, w, key, coeff, lr: float, *,
-                    impl: str = "pallas", interpret: bool | None = None):
-        """Fused kernels path (Rademacher directions only): the per-leaf
-        sign bits regenerate from the same per-leaf keys
-        ``direction_tree`` uses, so this is bit-compatible with
-        apply_from_seed(direction='rademacher'). ``impl='pallas'`` is the
-        TPU kernel (compiled on a TPU, interpreted elsewhere);
-        ``impl='xla'`` the one-dispatch host chain."""
-        assert self.direction == "rademacher", \
-            "the fused kernel derives u from sign bits (Rademacher law)"
-        scale = jnp.asarray(lr * coeff, jnp.float32)
-        return fused_round.zo_apply(w, key, scale, impl=impl,
-                                    interpret=interpret)
 
     # ---- server side (Algorithm 1 lines 9-11 / Eq. 17) -------------------
     def server_update(self, w0, key, f_base, f_of, lr: float):
@@ -443,7 +420,7 @@ class ZOExchange:
     # Instances hash by semantics so they can ride in jit static args.
     def _hash_key(self):
         return (self.mu, self.direction, self.lam, self.num_directions,
-                self.seed_replay, self.codec.name, self.dp, self.fused)
+                self.codec.name, self.dp, self.fused)
 
     def __hash__(self):
         return hash(self._hash_key())

@@ -13,23 +13,28 @@ direction law, exactly as in the paper.
 
 Seed-replay (beyond-paper, MeZO-style): the direction u never needs to be
 materialized in HBM — both the perturbation and the update regenerate it from
-the same PRNG key. ``zo_gradient_from_seed`` is that path; the fused TPU
-update lives in kernels/zo_update.py.
+the same PRNG key. ``apply_zo_update`` is that path; ``leaf_keys`` is the one
+per-leaf key split every draw uses, so the Pallas update kernel's tree
+wrappers (kernels/fused_round) replay the same streams.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.utils.prng import fold_name, sample_direction
+from repro.utils.prng import sample_direction
+
+
+def leaf_keys(key, tree):
+    """One PRNG key per leaf of ``tree``, in flatten order."""
+    return jax.random.split(key, len(jax.tree.leaves(tree)))
 
 
 def direction_tree(key, tree, dist: str):
     """One direction leaf per parameter leaf, deterministically keyed."""
     leaves, treedef = jax.tree.flatten(tree)
-    keys = jax.random.split(key, len(leaves))
     us = [sample_direction(k, leaf.shape, dist, jnp.float32)
-          for k, leaf in zip(keys, leaves)]
+          for k, leaf in zip(leaf_keys(key, tree), leaves)]
     return jax.tree.unflatten(treedef, us)
 
 
@@ -51,15 +56,10 @@ def zo_gradient(u_tree, coeff):
     return jax.tree.map(lambda u: coeff * u, u_tree)
 
 
-def zo_gradient_from_seed(key, tree, dist: str, coeff):
-    """Seed-replay variant: regenerate u from `key`; never store it."""
-    u = direction_tree(key, tree, dist)
-    return jax.tree.map(lambda d: coeff * d, u)
-
-
 def apply_zo_update(tree, key, dist: str, coeff, lr: float):
-    """w <- w - lr * coeff * u(key), regenerating u on the fly (fused-update
-    semantics; the Pallas kernel version is kernels/zo_update)."""
+    """w <- w - lr * coeff * u(key), regenerating u on the fly (the
+    seed-regenerated apply; the Pallas kernel's tree wrapper is
+    kernels/fused_round.zo_apply)."""
     u = direction_tree(key, tree, dist)
     return jax.tree.map(
         lambda w, d: (w.astype(jnp.float32)

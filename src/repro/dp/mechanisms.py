@@ -104,6 +104,5 @@ class DPExchange(ZOExchange):
     def wrap(cls, base: ZOExchange, dp: DPConfig) -> "DPExchange":
         """A defended copy of an existing exchange's semantics."""
         return cls(dp, mu=base.mu, direction=base.direction, lam=base.lam,
-                   num_directions=base.num_directions,
-                   seed_replay=base.seed_replay, codec=base.codec,
+                   num_directions=base.num_directions, codec=base.codec,
                    meter=base.meter, fused=base.fused)

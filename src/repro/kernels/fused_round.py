@@ -1,11 +1,12 @@
-"""Fused defended-round hot path: perturb / clip / DP-noise / quantize
-as single passes, bit-identical to the unfused seam.
+"""Fused defended-round hot path: clip / DP-noise / quantize as single
+passes, bit-identical to the unfused seam.
 
 One defended up-link (core/exchange.py ``encode_up``) is a chain of
 separately materialized steps — ``jnp.clip``, a mechanism noise draw,
 the add, then the codec's scale/round/cast — each an HBM round-trip on
 TPU and a separate eager dispatch on the CPU hosts. This module fuses
-the whole chain. Every op ships two interchangeable implementations:
+the whole chain. The release ops ship two interchangeable
+implementations:
 
   impl='xla'     ONE jitted elementwise chain — the production fast path
                  on CPU executors (a single dispatch replaces the
@@ -33,11 +34,15 @@ per-round key derivation (``_dp_key`` / ``_codec_key``) a fused exchange
 is therefore bitwise identical to the unfused one, and the TCP-vs-memory
 parity pins survive with ``fused=True`` unchanged.
 
-The perturb/apply side reuses kernels/zo_update.py: ``w + mu*u`` is the
-same kernel as ``w - scale*u`` at ``scale = -mu`` (IEEE subtraction of
-a negated product is exact), and the update's ``scale = lr*coeff``
-matches the oracle's ``w - (lr*coeff)*u`` evaluation order, so f32
-parameter parity is bitwise. See docs/kernels.md.
+The perturb/apply side has one implementation per law on the XLA path,
+core/zoo (which ZOExchange calls). ``perturb`` and ``zo_apply`` here are
+only the tree wrappers of the Pallas kernel in kernels/zo_update.py,
+drawing the Rademacher bits from zoo's per-leaf key split: ``w + mu*u``
+is the same kernel as ``w - scale*u`` at ``scale = -mu`` (IEEE
+subtraction of a negated product is exact), and the update's
+``scale = lr*coeff`` matches the oracle's ``w - (lr*coeff)*u``
+evaluation order, so f32 parameter parity is bitwise. See
+docs/kernels.md.
 """
 from __future__ import annotations
 
@@ -49,9 +54,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.zoo import leaf_keys
 from repro.kernels.platform import interpret_mode
 from repro.kernels.zo_update import (rounded_product, rounded_quotient,
                                      runtime_zero, zo_update_pallas)
+from repro.utils.prng import rademacher_from_bits
 
 # every payload pads to whole 1024-lane blocks: Mosaic lays a rank-1 int8
 # or bf16 block out in packed sublanes and refuses a block that does not
@@ -114,11 +121,6 @@ def laplace_from_bits(bits, z=None):   # zvlint: bit-exact
     u = _open_interval(uniform_from_bits(bits), _LAPLACE_LO, z)
     return jax.lax.mul(jax.lax.sign(u),
                        jax.lax.log1p(jax.lax.neg(jax.lax.abs(u))))
-
-
-def rademacher_from_bits(bits):
-    """== utils/prng.sample_direction(dist='rademacher'): the low bit."""
-    return jnp.where((bits & 1) == 1, 1.0, -1.0).astype(jnp.float32)
 
 
 _NOISE = {"gaussian": normal_from_bits, "laplace": laplace_from_bits}
@@ -346,63 +348,36 @@ def defend_fused(ex, c, key):
 # ------------------------------------------------- perturb / apply side ----
 
 def _leaf_bits(tree, key):
-    """The per-leaf (key, bits) split zoo.direction_tree uses — shared so
-    the fused paths replay the exact same streams."""
+    """Each leaf's raw uint32 stream, keyed by zoo.leaf_keys — the streams
+    zoo.direction_tree's Rademacher draw consumes."""
     leaves, treedef = jax.tree.flatten(tree)
-    keys = jax.random.split(key, len(leaves))
     bits = [jax.random.bits(k, leaf.shape, jnp.uint32)
-            for k, leaf in zip(keys, leaves)]
+            for k, leaf in zip(leaf_keys(key, tree), leaves)]
     return leaves, treedef, bits
 
 
-def zo_apply(w_tree, key, scale, *, impl: str = "xla",   # zvlint: bit-exact
+def zo_apply(w_tree, key, scale, *,   # zvlint: bit-exact
              interpret: bool | None = None):
-    """w - scale * u(key) with Rademacher u regenerated from the seed,
-    never stored. ``scale`` is lr*coeff (or -mu for a perturbation).
-    Bitwise equal to zoo.apply_zo_update(dist='rademacher') — impl='xla'
-    for every dtype, impl='pallas' for f32 leaves (both do f32 math and
-    cast out)."""
+    """w - scale * u(key) through the Pallas zo_update kernel, leaf by
+    leaf, with Rademacher u regenerated from the seed, never stored.
+    ``scale`` is lr*coeff (or -mu for a perturbation). Bitwise equal to
+    zoo.apply_zo_update(dist='rademacher') for f32 leaves."""
     leaves, treedef, bits = _leaf_bits(w_tree, key)
-    if impl == "pallas":
-        outs = [zo_update_pallas(leaf.reshape(-1), b.reshape(-1),
-                                 jnp.asarray(scale, jnp.float32),
-                                 interpret=interpret).reshape(leaf.shape)
-                for leaf, b in zip(leaves, bits)]
-    else:
-        # zvlint: disable=kernel-float-safety — EAGER oracle formula: this
-        # branch dispatches op-by-op, mirroring zoo.apply_zo_update
-        # verbatim; guarding it would change the very bits it pins
-        outs = [(leaf.astype(jnp.float32)
-                 - scale * rademacher_from_bits(b)).astype(leaf.dtype)
-                for leaf, b in zip(leaves, bits)]
+    scale = jnp.asarray(scale, jnp.float32)
+    outs = [zo_update_pallas(leaf.reshape(-1), b.reshape(-1), scale,
+                             interpret=interpret).reshape(leaf.shape)
+            for leaf, b in zip(leaves, bits)]
     return jax.tree.unflatten(treedef, outs)
 
 
-def perturb(w_tree, key, mu: float, *, impl: str = "xla",   # zvlint: bit-exact
+def perturb(w_tree, key, mu: float, *,   # zvlint: bit-exact
             interpret: bool | None = None):
-    """(w + mu*u, u) with Rademacher u — the fused twin of zoo.perturb.
-    The xla impl mirrors the oracle's formula exactly (bitwise for every
-    dtype); pallas routes through the zo_update kernel at scale=-mu
-    (bitwise for f32: subtracting the negated product is IEEE-exact)."""
-    leaves, treedef, bits = _leaf_bits(w_tree, key)
-    u = jax.tree.unflatten(treedef, [rademacher_from_bits(b) for b in bits])
-    if impl == "pallas":
-        pert = zo_apply(w_tree, key, np.float32(-mu), impl="pallas",
-                        interpret=interpret)
-    else:
-        # zvlint: disable=kernel-float-safety — EAGER oracle formula,
-        # mirroring zoo.perturb verbatim (see zo_apply's xla branch)
-        pert = jax.tree.map(lambda w, d: w + mu * d.astype(w.dtype),
-                            w_tree, u)
-    return pert, u
-
-
-def zo_gradient_from_seed(w_tree, key, coeff):
-    """coeff * u(key) — the fused twin of zoo.zo_gradient_from_seed for
-    Rademacher directions (same per-leaf key split, same low-bit law)."""
+    """(w + mu*u, u) with Rademacher u — zoo.perturb through the
+    zo_update kernel at scale=-mu (bitwise for f32 leaves: subtracting
+    the negated product is IEEE-exact)."""
     _, treedef, bits = _leaf_bits(w_tree, key)
-    return jax.tree.unflatten(
-        treedef, [coeff * rademacher_from_bits(b) for b in bits])
+    u = jax.tree.unflatten(treedef, [rademacher_from_bits(b) for b in bits])
+    return zo_apply(w_tree, key, np.float32(-mu), interpret=interpret), u
 
 
 @jax.jit

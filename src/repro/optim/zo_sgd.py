@@ -27,8 +27,7 @@ def zo_sgd_step(loss_fn, params, key, lr: float, mu: float,
     also routes payloads through ``ex.encode_up``/``roundtrip_up`` —
     passing it here keeps ONE exchange object across both uses."""
     if ex is None:
-        ex = ZOExchange(mu=mu, direction=dist,
-                        num_directions=num_directions, seed_replay=True)
+        ex = ZOExchange(mu=mu, direction=dist, num_directions=num_directions)
     f0 = loss_fn(params)
 
     def one(k):

@@ -20,6 +20,12 @@ def key_iter(key):
         yield sub
 
 
+def rademacher_from_bits(bits, dtype=jnp.float32):
+    """u_i = +1 where the low bit of ``bits`` is set, else -1: the
+    Rademacher law from a raw uint32 stream."""
+    return jnp.where((bits & 1) == 1, 1.0, -1.0).astype(dtype)
+
+
 def sample_direction(key, shape, dist: str, dtype=jnp.float32):
     """Random direction u for the two-point estimator.
 
@@ -29,9 +35,10 @@ def sample_direction(key, shape, dist: str, dtype=jnp.float32):
                        prefactor is shared — the paper's two theorems differ only
                        in the d_* constant.)
     dist='rademacher': u_i = ±1 (E[uu^T] = I — a valid two-point law, beyond
-                       paper). Signs derive from the low bit of the on-chip
-                       PRNG stream, bit-compatible with kernels/zo_update's
-                       fused seed-replay path (ZOExchange.apply_fused).
+                       paper). Signs derive from the low bit of the PRNG
+                       stream (``rademacher_from_bits``), bit-compatible
+                       with the Pallas zo_update kernel's tree wrappers
+                       (kernels/fused_round.zo_apply and perturb).
     """
     if dist == "gaussian":
         return jax.random.normal(key, shape, dtype)
@@ -41,6 +48,6 @@ def sample_direction(key, shape, dist: str, dtype=jnp.float32):
         u = g / (jnp.linalg.norm(g.reshape(-1)) + 1e-12) * jnp.sqrt(float(d))
         return u.astype(dtype)
     elif dist == "rademacher":
-        bits = jax.random.bits(key, shape, jnp.uint32)
-        return jnp.where((bits & 1) == 1, 1.0, -1.0).astype(dtype)
+        return rademacher_from_bits(jax.random.bits(key, shape, jnp.uint32),
+                                    dtype)
     raise ValueError(f"unknown direction distribution: {dist}")

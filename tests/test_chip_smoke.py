@@ -173,8 +173,6 @@ def _wrappers():
     return {
         "ops.dual_matmul": lambda: ops.dual_matmul(m, m, m, mu=1e-2),
         "ops.flash_attention": lambda: ops.flash_attention(qkv, qkv, qkv),
-        "ops.zo_update": lambda: ops.zo_update({"w": flat}, {"w": bits},
-                                               0.1),
         "dual_matmul_pallas": lambda: dual_matmul_pallas(m, m, m, mu=1e-2),
         "flash_attention_pallas": lambda: flash_attention_pallas(
             qkv[0].transpose(1, 0, 2), qkv[0].transpose(1, 0, 2),
@@ -188,19 +186,15 @@ def _wrappers():
         "roundtrip_up_fused": lambda: fused_round.roundtrip_up_fused(
             ex, flat, key, impl="pallas"),
         "zo_apply": lambda: fused_round.zo_apply(
-            {"w": flat}, key, jnp.float32(0.1), impl="pallas"),
-        "perturb": lambda: fused_round.perturb({"w": flat}, key, 1e-3,
-                                               impl="pallas"),
-        "ZOExchange.apply_fused": lambda: ex.apply_fused(
-            {"w": flat}, key, jnp.float32(0.5), 1e-2),
+            {"w": flat}, key, jnp.float32(0.1)),
+        "perturb": lambda: fused_round.perturb({"w": flat}, key, 1e-3),
     }
 
 
-WRAPPERS = ("ops.dual_matmul", "ops.flash_attention", "ops.zo_update",
+WRAPPERS = ("ops.dual_matmul", "ops.flash_attention",
             "dual_matmul_pallas", "flash_attention_pallas",
             "zo_update_pallas", "defended_encode", "encode_up_fused",
-            "roundtrip_up_fused", "zo_apply", "perturb",
-            "ZOExchange.apply_fused")
+            "roundtrip_up_fused", "zo_apply", "perturb")
 
 
 def test_wrapper_list_is_complete():

@@ -13,6 +13,7 @@ from repro.core import asyrevel, comms
 from repro.core.exchange import (CommsMeter, ZOExchange, get_codec,
                                  wire_nbytes)
 from repro.core.vfl import PaperLRModel, pad_features
+from repro.kernels import fused_round
 from repro.utils.prng import fold_name
 
 
@@ -117,15 +118,15 @@ def test_host_executor_bytes_sourced_from_codec():
 # ----------------------------------------------------- update applies -----
 
 def test_fused_apply_matches_seed_replay_rademacher():
-    """ZOExchange.apply_fused (the Pallas zo_update kernel) must be
+    """fused_round.zo_apply (the Pallas zo_update kernel) must be
     bit-compatible with the dense seed-replay path: same per-leaf key
     split, same sign convention."""
-    ex = ZOExchange(mu=1e-3, direction="rademacher", seed_replay=True)
+    ex = ZOExchange(mu=1e-3, direction="rademacher")
     key = jax.random.key(3)
     w = {"a": jax.random.normal(jax.random.fold_in(key, 1), (300,)),
          "b": jax.random.normal(jax.random.fold_in(key, 2), (7, 5))}
     dense = ex.apply_from_seed(w, key, coeff=2.0, lr=0.1)
-    fused = ex.apply_fused(w, key, coeff=2.0, lr=0.1)
+    fused = fused_round.zo_apply(w, key, jnp.float32(0.1 * 2.0))
     for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(fused)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-6)
@@ -134,12 +135,12 @@ def test_fused_apply_matches_seed_replay_rademacher():
 def test_fused_apply_awkward_leaf_sizes():
     """Leaves whose 256-padded length is not a multiple of 1024 (e.g.
     1100 -> 1280) must still go through the kernel block plumbing."""
-    ex = ZOExchange(mu=1e-3, direction="rademacher", seed_replay=True)
+    ex = ZOExchange(mu=1e-3, direction="rademacher")
     key = jax.random.key(4)
     for n in (1100, 1025, 257, 3):
         w = {"a": jax.random.normal(key, (n,))}
         dense = ex.apply_from_seed(w, key, coeff=1.0, lr=0.1)
-        fused = ex.apply_fused(w, key, coeff=1.0, lr=0.1)
+        fused = fused_round.zo_apply(w, key, jnp.float32(0.1 * 1.0))
         np.testing.assert_allclose(np.asarray(dense["a"]),
                                    np.asarray(fused["a"]), atol=1e-6)
 
@@ -148,7 +149,7 @@ def test_rademacher_direction_through_trainer():
     """AsyREVEL runs end-to-end with the fused-kernel direction law."""
     model, data = _lr_setup()
     vfl = VFLConfig(num_parties=4, mu=1e-3, lr_party=1e-2, lr_server=1e-3,
-                    max_delay=2, direction="rademacher", seed_replay=True)
+                    max_delay=2, direction="rademacher")
     state, losses = asyrevel.train(model, vfl, data, jax.random.key(0),
                                    steps=30, batch_size=8)
     assert np.isfinite(np.asarray(losses)).all()
@@ -156,17 +157,18 @@ def test_rademacher_direction_through_trainer():
 
 # ------------------------------------------------- cross-path parity ------
 
-def test_device_scan_and_host_executor_same_party_update():
+@pytest.mark.parametrize("K", [1, 3])
+def test_device_scan_and_host_executor_same_party_update(K):
     """The tentpole invariant: given identical seeds/batches/initial
     state, the jit device-scan trainer and the threaded host executor
     produce the SAME party update, because both route the round through
     the shared ZOExchange (perturb with the same key, same coefficient,
-    same apply)."""
+    same apply; for K > 1 the same mean over the directions)."""
     from repro.core.async_host import HostAsyncTrainer
     q, B = 4, 8
     model, data = _lr_setup(q=q)
     vfl = VFLConfig(num_parties=q, mu=1e-3, lr_party=1e-2, lr_server=0.0,
-                    max_delay=0, perturb_server=False)
+                    max_delay=0, perturb_server=False, num_directions=K)
     state = asyrevel.init_state(model, vfl, jax.random.key(0))
     batch = jax.tree.map(lambda a: a[:B], data)
     new_state, h = asyrevel.asyrevel_step(model, vfl, state, batch)

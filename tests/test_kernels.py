@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.zo_update import zo_update_pallas
 
 KEY = jax.random.key(42)
 
@@ -102,7 +103,8 @@ def test_flash_matches_model_blocked_attention():
 def test_zo_update_sweep(shape, dtype):
     w = _rand(shape, dtype, 16)
     bits = jax.random.bits(jax.random.fold_in(KEY, 17), shape, jnp.uint32)
-    out = ops.zo_update({"w": w}, {"w": bits}, 0.05)["w"]
+    out = zo_update_pallas(w.reshape(-1), bits.reshape(-1),
+                           jnp.float32(0.05)).reshape(shape)
     expect = ref.zo_update_ref(w, bits, jnp.float32(0.05))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), atol=1e-6)
@@ -112,7 +114,7 @@ def test_zo_update_is_rademacher_step():
     """Update must move every coordinate by exactly +-scale."""
     w = jnp.zeros((512,), jnp.float32)
     bits = jax.random.bits(jax.random.fold_in(KEY, 18), (512,), jnp.uint32)
-    out = ops.zo_update({"w": w}, {"w": bits}, 0.1)["w"]
+    out = zo_update_pallas(w, bits, jnp.float32(0.1))
     np.testing.assert_allclose(np.abs(np.asarray(out)), 0.1, atol=1e-7)
     # roughly balanced signs
     assert 0.3 < float(jnp.mean(out > 0)) < 0.7
